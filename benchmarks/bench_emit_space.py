@@ -3,7 +3,11 @@
 PR 9 replaced every kernel's hand-enumerated block ladder with spaces
 *emitted* from the architecture model (core/arch.py + core/emit.py).  This
 bench freezes the old hand ladders (copied verbatim from the pre-emit
-``ops.py`` files, 16 MiB VMEM budget) and gates the migration per kernel:
+``ops.py`` files, 16 MiB VMEM budget) and gates the migration per kernel.
+One cut was made to the frozen ladders since: stress ``block_j`` values
+that are neither a multiple of the 8-row sublane nor the whole ``nj`` are
+dropped, because ``block_j`` is the second-minor dim of the stress block and
+the TPU compiler refuses such a block shape.  The gates:
 
 * **superset** — every feasible hand point is still in the emitted space
   (the union escape hatch means the model can only *add* candidates here);
@@ -104,8 +108,10 @@ def _hand_space(name, bp):
         divs = lambda n: tuple(
             d for d in (1, 2, 4, 8, 16, 32, 64) if n % d == 0 and d <= n
         )
+        # whole sublanes only: the TPU compiler refuses the rest
+        j_blocks = tuple(d for d in divs(nj) if d % 8 == 0 or d == nj)
         return ParamSpace(
-            [PerfParam("block_k", divs(nk)), PerfParam("block_j", divs(nj))],
+            [PerfParam("block_k", divs(nk)), PerfParam("block_j", j_blocks)],
             constraint=lambda p: vmem_bytes(p["block_k"], p["block_j"], ni)
             <= LEGACY_VMEM_BUDGET,
         )

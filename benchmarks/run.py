@@ -36,6 +36,9 @@ def main() -> None:
         bench_tune_throughput,
         common,
     )
+    from repro.launch import use_compile_cache  # common put src on the path
+
+    use_compile_cache()
 
     failures = []
     for mod in (

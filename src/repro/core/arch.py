@@ -28,8 +28,9 @@ class ArchSpec:
     """One target architecture, as seen by the emit layer.
 
     ``vmem_bytes`` is the physical on-chip fast-memory capacity;
-    :meth:`vmem_budget` is what a single kernel invocation may plan
-    against (half, leaving room for double buffering + compiler slack).
+    :meth:`vmem_limit_bytes` is the scoped VMEM every ``pallas_call``
+    requests from the compiler, and :meth:`vmem_budget` is what one
+    kernel's single-buffered working set may plan against.
     """
 
     name: str
@@ -53,9 +54,17 @@ class ArchSpec:
         init=False, repr=False, compare=False,
     )
 
+    def vmem_limit_bytes(self) -> int:
+        """Scoped VMEM a kernel asks the compiler for: three quarters of
+        the physical capacity, the rest left to Mosaic's internal scratch.
+        Without it the compiler holds a kernel to its much smaller default
+        scope and refuses the larger emitted tiles."""
+        return self.vmem_bytes * 3 // 4
+
     def vmem_budget(self) -> int:
-        """Bytes one kernel's working set may plan to keep resident."""
-        return self.vmem_bytes // 2
+        """Bytes one kernel's working set may plan to keep resident: half
+        the requested limit, since the pipeline double-buffers each block."""
+        return self.vmem_limit_bytes() // 2
 
     def bp_entries(self) -> Dict[str, Any]:
         """This arch as composable BP entries (``arch_`` prefix)."""
@@ -80,14 +89,15 @@ class ArchSpec:
 # Known architecture table. Interpret-mode hosts still emit TPU-shaped
 # tiles — the arch model describes the Pallas *target*, with a VMEM
 # budget sized so the interpreter's working sets stay cache-resident
-# (16 MiB planning budget, matching the historical hand-tuned cap).
+# (an 18 MiB planning budget, no less than the historical 16 MiB
+# hand-tuned cap, so every hand-ladder tile is still emitted here).
 _CPU_HOST = ArchSpec(
     name="cpu_host",
     backend="cpu",
     lane_width=128,
     sublane_width=8,
     mxu_dim=128,
-    vmem_bytes=32 * 2**20,
+    vmem_bytes=48 * 2**20,
     cacheline_bytes=64,
     hbm_bandwidth=50e9,
     peak_flops=0.5e12,
@@ -105,14 +115,6 @@ _TPU_V5E = ArchSpec(
     peak_flops=197e12,
 )
 
-_TPU_V4 = ArchSpec(
-    name="tpu_v4",
-    backend="tpu",
-    vmem_bytes=128 * 2**20,
-    hbm_bandwidth=1200e9,
-    peak_flops=275e12,
-)
-
 _GPU_GENERIC = ArchSpec(
     name="gpu_generic",
     backend="gpu",
@@ -124,6 +126,25 @@ _GPU_GENERIC = ArchSpec(
 )
 
 
+# TPU generations by the exact ``device_kind`` JAX reports.  A chip that is
+# not listed is an error: planning its tiles against another chip's VMEM
+# would emit candidates its compiler refuses.
+_TPU_BY_KIND: Dict[str, ArchSpec] = {
+    "TPU v5 lite": _TPU_V5E,
+}
+
+
+def tpu_arch(device_kind: str) -> ArchSpec:
+    """The ArchSpec of one TPU generation, by its ``device_kind``."""
+    try:
+        return _TPU_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no ArchSpec for TPU device_kind {device_kind!r}; "
+            f"known: {sorted(_TPU_BY_KIND)}"
+        ) from None
+
+
 def detect(backend: Optional[str] = None) -> ArchSpec:
     """Resolve the ArchSpec for a backend (default: the local one)."""
     import jax
@@ -131,19 +152,12 @@ def detect(backend: Optional[str] = None) -> ArchSpec:
     if backend is None:
         backend = jax.default_backend()
     if backend == "tpu":
-        try:
-            devices = jax.devices()
-            kind = devices[0].device_kind.lower()
-        except Exception:  # pragma: no cover - device query race
-            devices, kind = [], ""
-        base = _TPU_V4 if "v4" in kind else _TPU_V5E
-        return dataclasses.replace(base, core_count=max(1, len(devices)))
+        devices = jax.devices()
+        return dataclasses.replace(
+            tpu_arch(devices[0].device_kind), core_count=len(devices)
+        )
     if backend == "gpu":
-        try:
-            n = len(jax.devices())
-        except Exception:  # pragma: no cover
-            n = 1
-        return dataclasses.replace(_GPU_GENERIC, core_count=max(1, n))
+        return dataclasses.replace(_GPU_GENERIC, core_count=len(jax.devices()))
     return _CPU_HOST
 
 
@@ -161,10 +175,16 @@ def local_arch() -> ArchSpec:
 
 
 def default_interpret() -> bool:
-    """Pallas interpret-mode default: only when no accelerator is present."""
+    """Pallas interpret mode runs the kernels on the CPU backend only (the
+    tests); on a TPU they compile natively.  Any other backend has no
+    lowering for these TPU kernels, so it is refused rather than
+    silently interpreted."""
     import jax
 
-    return jax.default_backend() == "cpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on {backend!r}")
+    return backend == "cpu"
 
 
 def arch_bp_entries(arch: Optional[ArchSpec] = None) -> Dict[str, Any]:

@@ -28,6 +28,7 @@ The life of a call ``autotuned("flash_attention")(q, k, v)``:
 from __future__ import annotations
 
 import json
+import math
 import threading
 import time
 from dataclasses import dataclass, field
@@ -507,9 +508,11 @@ class AutotunedOp:
         """
         region, bp = state.region, state.bp
         search = search or self.search or self._default_search(state, args, kwargs)
-        if self.cost_factory is not None:
-            cost = self.cost_factory(region, bp, args, kwargs)
-        else:
+        cost = (
+            self.cost_factory(region, bp, args, kwargs)
+            if self.cost_factory is not None else None
+        )
+        if cost is None:
             # a staged search's prescreen keeps its compiled executables;
             # the measured stage runs on the same example args, so survivors
             # execute those artifacts instead of compiling a second time
@@ -575,6 +578,9 @@ class AutotunedOp:
             "prescreen_evaluations": result.prescreen_evaluations,
         }
         if result.prescreen_costs:
+            payload["prescreen_excluded"] = sum(
+                1 for c in result.prescreen_costs.values() if not math.isfinite(c)
+            )
             ranked = sorted(
                 result.prescreen_costs.items(), key=lambda kv: (kv[1], kv[0])
             )

@@ -255,7 +255,9 @@ def score_points_concurrently(
     The single shared policy for prescreen fan-out (XLA lowering/compilation
     release the GIL): `CompiledRooflineCost.score_many` and
     `StagedSearch`'s generic prescreen both delegate here, so the worker
-    bound and the exclude-don't-fail error handling cannot diverge.
+    bound and the exclude-don't-fail error handling cannot diverge.  The
+    exclusions are counted: ``search_completed`` events record them as
+    ``prescreen_excluded``.
     """
     workers = max_workers or min(8, os.cpu_count() or 2)
 
@@ -303,13 +305,13 @@ class WallClockCost(CostFunction):
     def __call__(self, point: Mapping[str, Any]) -> float:
         fn = self.build(point)
         for _ in range(self.warmup):
-            _block(fn())
+            jax.block_until_ready(fn())
         best = math.inf
         for _ in range(self.repeats):
             t0 = time.perf_counter()
             for _ in range(self.inner_iters):
                 out = fn()
-            _block(out)
+            jax.block_until_ready(out)
             best = min(best, (time.perf_counter() - t0) / self.inner_iters)
         return best
 
@@ -360,13 +362,13 @@ class AdaptiveWallClockCost(CostFunction):
     ) -> float:
         fn = self.build(point)
         for _ in range(self.warmup):
-            _block(fn())
+            jax.block_until_ready(fn())
         cap = self.max_repeats * max(1, int(budget or 1))
         times: List[float] = []
         while len(times) < cap:
             t0 = time.perf_counter()
             out = fn()
-            _block(out)
+            jax.block_until_ready(out)
             times.append(time.perf_counter() - t0)
             self.timed_runs += 1
             if len(times) < self.min_repeats:
@@ -489,10 +491,3 @@ def roofline_prescreen(
         return jax.jit(region.instantiate(point)).lower(*args, **kwargs)
 
     return CompiledRooflineCost(lower, n_chips=1, keep_compiled=True)
-
-
-def _block(x: Any) -> Any:
-    try:
-        return jax.block_until_ready(x)
-    except Exception:
-        return x

@@ -21,23 +21,20 @@ import json
 import math
 from dataclasses import dataclass, field
 from typing import (
-    Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple,
+    Any, Callable, Dict, List, Mapping, Optional, Protocol, Sequence, Tuple,
 )
 
 from .arch import ArchSpec, local_arch
 from .params import EmptySpace, ParamSpace, PerfParam, pp_key
 
-try:  # pragma: no cover - Protocol is cosmetic on older pythons
-    from typing import Protocol
-except ImportError:  # pragma: no cover
-    Protocol = object  # type: ignore[assignment]
-
 
 # Dimension semantics → the smallest tile worth emitting.  "lane" dims map
 # to the VPU minor axis (tiles below lane width waste the vector unit);
-# "sequential" dims are loop-carried chunks (a few sublanes deep is the
-# floor); "grid" dims are pure program-count splits (any size works).
-_SEMANTICS = ("lane", "sequential", "grid")
+# "sublane" dims are the second-minor block axis, which Mosaic tiles in
+# whole sublanes; "sequential" dims are loop-carried chunks (a few
+# sublanes deep is the floor); "grid" dims are pure program-count splits
+# (any size works).
+_SEMANTICS = ("lane", "sublane", "sequential", "grid")
 
 
 @dataclass(frozen=True)
@@ -69,6 +66,8 @@ class TileDim:
             return max(1, self.min_tile)
         if self.semantic == "lane":
             return arch.lane_width
+        if self.semantic == "sublane":
+            return arch.sublane_width
         if self.semantic == "sequential":
             return arch.sublane_width * 4
         return 1

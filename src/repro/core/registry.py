@@ -50,7 +50,8 @@ class KernelSpec:
     ``make_region(bp)`` builds the candidate family for that class.
     ``cost_factory(region, bp, args, kwargs)``, when given, returns the cost
     function the tuner minimizes (e.g. an analytic model for install-time AT
-    on a host without the target hardware); the default is wall-clock.
+    on a host without the target hardware); the default, and what a factory
+    that returns ``None`` defers to, is wall-clock.
     ``traffic_class(*args, **kwargs)``, when given, maps the call to a
     :class:`~repro.core.traffic.TrafficClass`; its entries extend the shape
     class BP, so each traffic class tunes — and hot-swaps — independently
@@ -163,12 +164,11 @@ class Registry:
     def _import_providers(self) -> None:
         if self._imported_providers:
             return
-        self._imported_providers = True
         for mod in self._providers:
-            try:
-                importlib.import_module(mod)
-            except ImportError:  # pragma: no cover - missing optional provider
-                pass
+            # a provider that fails to import is a broken install: raise,
+            # rather than serving a registry that silently lacks its kernels
+            importlib.import_module(mod)
+        self._imported_providers = True
 
 
 # The process-wide registry.  ``repro.kernels`` registers the five Pallas

@@ -443,6 +443,10 @@ class FleetCoordinator:
                 idx, points, bp.asdict(), cost, layer,
                 self._scratch_path(idx), self.sync_every,
             ))
+        # Children must never initialise a JAX backend: on a TPU host the
+        # parent already holds the chip, and a child that reaches for it
+        # fails or hangs.  That is why this backend only runs analytic
+        # costs (launch/fleet.py allows it for the ``demo`` kernel alone).
         ctx = mp.get_context("spawn")
         outcomes: Dict[int, Tuple[List[Tuple[Dict, float]], float, int]] = {}
         crashed: List[int] = []

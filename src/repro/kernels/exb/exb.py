@@ -27,25 +27,31 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.arch import local_arch
 
 from .ref import CEF, CS1
 
 
 def _exb_kernel(
-    vl_ref,
+    vl_ref,  # (iv,) in SMEM: the whole velocity grid, read as scalars
     df1_re_ref, df1_im_ref, df2_re_ref, df2_im_ref,
     ex_re_ref, ex_im_ref, ey_re_ref, ey_im_ref,
     bx_re_ref, bx_im_ref, by_re_ref, by_im_ref,
     out_re_ref, out_im_ref,
+    *,
+    block_iv: int,
 ):
-    vl = vl_ref[...][:, None, None, None]  # (biv,1,1,1)
-    cs1vl = CS1 * vl
-    ey_re = ey_re_ref[...][None] - cs1vl * by_re_ref[...][None]
-    ey_im = ey_im_ref[...][None] - cs1vl * by_im_ref[...][None]
-    ex_re = ex_re_ref[...][None] - cs1vl * bx_re_ref[...][None]
-    ex_im = ex_im_ref[...][None] - cs1vl * bx_im_ref[...][None]
-    out_re_ref[...] = (df1_re_ref[...] * ey_re - df2_re_ref[...] * ex_re) * CEF
-    out_im_ref[...] = (df1_im_ref[...] * ey_im - df2_im_ref[...] * ex_im) * CEF
+    iv0 = pl.program_id(0) * block_iv
+    for b in range(block_iv):  # static: one (biz, mx, my) slab per iv
+        cs1vl = CS1 * vl_ref[iv0 + b]
+        ey_re = ey_re_ref[...] - cs1vl * by_re_ref[...]
+        ey_im = ey_im_ref[...] - cs1vl * by_im_ref[...]
+        ex_re = ex_re_ref[...] - cs1vl * bx_re_ref[...]
+        ex_im = ex_im_ref[...] - cs1vl * bx_im_ref[...]
+        out_re_ref[b] = (df1_re_ref[b] * ey_re - df2_re_ref[b] * ex_re) * CEF
+        out_im_ref[b] = (df1_im_ref[b] * ey_im - df2_im_ref[b] * ex_im) * CEF
 
 
 def exb_pallas(
@@ -63,18 +69,21 @@ def exb_pallas(
         (block_iv, block_iz, mx, my), lambda i, j: (i, j, 0, 0)
     )
     b3 = pl.BlockSpec((block_iz, mx, my), lambda i, j: (j, 0, 0))  # drops iv
-    bvl = pl.BlockSpec((block_iv,), lambda i, j: (i,))
+    bvl = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     out_shape = [
         jax.ShapeDtypeStruct((iv, iz, mx, my), jnp.float32),
         jax.ShapeDtypeStruct((iv, iz, mx, my), jnp.float32),
     ]
     fn = pl.pallas_call(
-        _exb_kernel,
+        functools.partial(_exb_kernel, block_iv=block_iv),
         grid=grid,
         in_specs=[bvl] + [b4] * 4 + [b3] * 8,
         out_specs=[b4, b4],
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=local_arch().vmem_limit_bytes()
+        ),
         interpret=interpret,
     )
     args = [
@@ -88,8 +97,10 @@ def exb_pallas(
 
 
 def vmem_bytes(block_iv: int, block_iz: int, mx: int = 128, my: int = 65) -> int:
-    """VMEM working set of one program instance (feasibility constraint)."""
+    """VMEM working set of one program instance (feasibility constraint):
+    the 4-D and 3-D blocks plus the four (block_iz, mx, my) field
+    temporaries of one iv slab (vl lives in SMEM)."""
     pad_my = -(-my // 128) * 128  # lane padding on real TPU
     b4 = block_iv * block_iz * mx * pad_my * 4
     b3 = block_iz * mx * pad_my * 4
-    return 6 * b4 + 8 * b3 + block_iv * 4  # 4 in + 2 out 4-D, 8 3-D, vl
+    return 6 * b4 + 12 * b3  # 4 in + 2 out 4-D, 8 3-D in + 4 temporaries

@@ -6,33 +6,42 @@ TPU flash layout: sequential grid = free accumulator carry).  Block shapes
 (block_q, block_kv) are the AT knobs: q/k/v tiles must fit VMEM and the
 MXU wants both ≥ 128.
 
+Heads are fused into the minor axis: ``(B, S, H, hd)`` is viewed as
+``(B, S, H*hd)`` (a free reshape) and a block is one head's
+``(block, hd)`` slab, so the two minor block dims are the sequence tile and
+the head dim — the layout Mosaic tiles in (8, 128) units.  A head dim that
+is not a lane multiple is zero-padded up to one (scores and outputs are
+unchanged; the pad columns are sliced off).
+
 GQA is handled in the index maps: the KV block index ignores the query-head
 grid coordinate beyond h // G — no KV replication in HBM.
 
 Compared to the XLA path (models.attention.flash_attention_xla), the score
-block never leaves VMEM — on the tinyllama train cell the XLA path's score
-round-trips are ~60 % of its memory-roofline term (EXPERIMENTS.md §Perf).
+block never leaves VMEM.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.arch import local_arch
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+LANES = 128
 
 
 def _flash_kernel(
-    q_ref,   # (1, block_q, 1, hd)
-    k_ref,   # (1, block_kv, 1, hd)
-    v_ref,   # (1, block_kv, 1, hd)
-    o_ref,   # (1, block_q, 1, hd)
-    m_ref,   # scratch (block_q,)
-    l_ref,   # scratch (block_q,)
+    q_ref,   # (block_q, hd)
+    k_ref,   # (block_kv, hd)
+    v_ref,   # (block_kv, hd)
+    o_ref,   # (block_q, hd)
+    m_ref,   # scratch (block_q, 1)
+    l_ref,   # scratch (block_q, 1)
     acc_ref,  # scratch (block_q, hd)
     *,
     causal: bool,
@@ -51,38 +60,36 @@ def _flash_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :]  # (bq, hd)
-    k = k_ref[0, :, 0, :]  # (bkv, hd)
-    v = v_ref[0, :, 0, :]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if nkv * block_kv > seq_len:
-        # padded tail block: keys past the real sequence must not score
+    q = q_ref[...]
+    k = k_ref[...]
+    v = v_ref[...]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    if nkv * block_kv > seq_len or causal:
         col = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1) \
             + kj * block_kv
-        s = jnp.where(col < seq_len, s, NEG_INF)
-    if causal:
-        off = qi * block_q - kj * block_kv
-        mask = (
-            jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0) + off
-            >= jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1)
-        )
-        s = jnp.where(mask, s, NEG_INF)
+        # padded tail block: keys past the real sequence must not score
+        keep = col < seq_len
+        if causal:
+            row = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 0) \
+                + qi * block_q
+            keep = jnp.logical_and(keep, row >= col)
+        s = jnp.where(keep, s, NEG_INF)
 
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32
     )
     m_ref[...] = m_new
 
     @pl.when(kj == nkv - 1)
     def _finish():
-        o_ref[0, :, 0, :] = (
-            acc_ref[...] / l_ref[...][:, None]
-        ).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -103,17 +110,18 @@ def flash_attention(
     # tail query rows are garbage and sliced off below)
     nq, nkv = -(-S // bq), -(-S // bkv)
     Sq, Skv = nq * bq, nkv * bkv
-    if Sq != S:
-        q = jnp.pad(q, ((0, 0), (0, Sq - S), (0, 0), (0, 0)))
-    if Skv != S:
-        pad_kv = ((0, 0), (0, Skv - S), (0, 0), (0, 0))
-        k = jnp.pad(k, pad_kv)
-        v = jnp.pad(v, pad_kv)
+    hdp = -(-hd // LANES) * LANES
+    q = jnp.pad(q, ((0, 0), (0, Sq - S), (0, 0), (0, hdp - hd)))
+    k, v = (
+        jnp.pad(t, ((0, 0), (0, Skv - S), (0, 0), (0, hdp - hd))) for t in (k, v)
+    )
+    q = q.reshape(B, Sq, H * hdp)
+    k = k.reshape(B, Skv, KV * hdp)
+    v = v.reshape(B, Skv, KV * hdp)
     grid = (B, H, nq, nkv)
 
-    q_spec = pl.BlockSpec((1, bq, 1, hd), lambda b, h, i, j: (b, i, h, 0))
-    kv_spec = pl.BlockSpec((1, bkv, 1, hd), lambda b, h, i, j: (b, j, h // G, 0))
-    o_spec = pl.BlockSpec((1, bq, 1, hd), lambda b, h, i, j: (b, i, h, 0))
+    q_spec = pl.BlockSpec((None, bq, hdp), lambda b, h, i, j: (b, i, h))
+    kv_spec = pl.BlockSpec((None, bkv, hdp), lambda b, h, i, j: (b, j, h // G))
 
     kernel = functools.partial(
         _flash_kernel,
@@ -128,28 +136,27 @@ def flash_attention(
         kernel,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Sq, H * hdp), q.dtype),
         scratch_shapes=[
-            _scratch((bq,), jnp.float32),
-            _scratch((bq,), jnp.float32),
-            _scratch((bq, hd), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, hdp), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=local_arch().vmem_limit_bytes()
+        ),
         interpret=interpret,
     )(q, k, v)
-    return out[:, :S] if Sq != S else out
-
-
-def _scratch(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
+    return out.reshape(B, Sq, H, hdp)[:, :S, :, :hd]
 
 
 def vmem_bytes(block_q: int, block_kv: int, hd: int) -> int:
-    pad = lambda n: -(-n // 128) * 128
-    q = block_q * pad(hd) * 2
-    kv = 2 * block_kv * pad(hd) * 2
-    s = block_q * pad(block_kv) * 4
-    scr = block_q * 4 * 2 + block_q * pad(hd) * 4
-    return q + kv + s + scr + block_q * pad(hd) * 2
+    """Single-buffered working set of one program: the q/k/v/o blocks, the
+    f32 scratch, and the (block_q, block_kv) f32 temporaries the body keeps
+    live at once (scores, probabilities, mask)."""
+    pad = lambda n: -(-n // LANES) * LANES
+    blocks = (2 * block_q + 2 * block_kv) * pad(hd) * 2
+    scratch = 2 * block_q * LANES * 4 + block_q * pad(hd) * 4
+    temps = 3 * block_q * pad(block_kv) * 4
+    return blocks + scratch + temps

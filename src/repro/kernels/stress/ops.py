@@ -30,11 +30,12 @@ def _traffic(bp: Mapping[str, Any], point: Mapping[str, Any]):
 
 STRESS_POLICY = TilePolicy(
     kernel="stress",
-    # both block dims are pure grid splits of the outer loops (the paper's
-    # Seism3D update_stress nest); the inner ni stays whole per program
+    # both block dims split the outer loops (the paper's Seism3D
+    # update_stress nest); the inner ni stays whole per program.  block_j
+    # is the second-minor block axis, so it comes in whole sublanes
     dims=lambda bp: (
         TileDim("block_k", bp["nk"], semantic="grid"),
-        TileDim("block_j", bp["nj"], semantic="grid"),
+        TileDim("block_j", bp["nj"], semantic="sublane"),
     ),
     vmem_model=lambda bp, p: vmem_bytes(p["block_k"], p["block_j"], bp["ni"]),
     traffic_model=_traffic,

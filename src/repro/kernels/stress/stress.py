@@ -12,6 +12,9 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.arch import local_arch
 
 from .ref import DT, INPUT_NAMES
 
@@ -51,6 +54,9 @@ def stress_pallas(
         in_specs=[spec] * len(INPUT_NAMES),
         out_specs=[spec] * 6,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=local_arch().vmem_limit_bytes()
+        ),
         interpret=interpret,
     )
     outs = fn(*[inp[n] for n in INPUT_NAMES])
@@ -58,5 +64,8 @@ def stress_pallas(
 
 
 def vmem_bytes(block_k: int, block_j: int, ni: int) -> int:
+    """Single-buffered working set: the 17 input and 6 output blocks plus
+    the five shared temporaries the body keeps live (rm2, rlrm2, d3 and
+    two partial sums)."""
     pad_i = -(-ni // 128) * 128
-    return (len(INPUT_NAMES) + 6) * block_k * block_j * pad_i * 4
+    return (len(INPUT_NAMES) + 6 + 5) * block_k * block_j * pad_i * 4

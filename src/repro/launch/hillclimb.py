@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # placeholder devices only: never the chip
 
 """§Perf hillclimb driver: hypothesis → change → re-lower → measure cycles
 on the three selected cells (see EXPERIMENTS.md §Perf for the narrative).

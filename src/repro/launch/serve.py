@@ -20,13 +20,20 @@ queue, and ``--chaos-seed`` runs the trace under the seeded
 :class:`~repro.runtime.chaos.ChaosInjector` (transient step faults, KV
 squeezes, delays) on the adversarial trace.  The run exits non-zero if the
 hardened engine fails to retire every request exactly once — the drain
-contract the chaos-smoke CI job asserts.
+contract the chaos-smoke CI job asserts.  Without ``--chaos-seed`` it also
+exits non-zero when any request retires ``error`` or background tuning
+fails or does not drain: nothing injected those faults, so they are bugs.
+
+``chip_smoke.py`` drives the stream path through the same functions
+(:func:`parse_args`, :func:`load_model`, :func:`make_background_tuner`,
+:func:`run_stream`).
 """
 import argparse
 import sys
+from typing import Any, List, Optional, Sequence, Tuple
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=4)
@@ -37,6 +44,8 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--full", action="store_true")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the random model parameters")
     ap.add_argument(
         "--trace", choices=("uniform", "mixed", "bursty"), default="uniform",
         help="uniform: identical requests; mixed: prefill/decode-heavy mix; "
@@ -151,7 +160,12 @@ def main() -> None:
              "costs — concurrent measured timings on one device reflect "
              "contention)",
     )
-    args = ap.parse_args()
+    return ap
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.drift_factor and not args.background_tune:
         ap.error("--drift-factor requires --background-tune "
                  "(an inline re-tune would run the search on the hot path)")
@@ -175,151 +189,212 @@ def main() -> None:
             if val is not None:
                 ap.error(f"{flag} requires --stream (the static Server has "
                          "no admission queue to bound)")
+    return args
 
-    import jax
 
-    from repro.configs import get_config
-    from repro.core import TuningDB
+def make_requests(cfg: Any, args: argparse.Namespace) -> List[Any]:
+    """The request trace ``args`` selects."""
     from repro.data import (
         adversarial_trace, bursty_open_loop_trace, mixed_traffic_trace,
         synthetic_requests,
     )
-    from repro.fleet import DriftMonitor, FleetCoordinator
-    from repro.models import init_params, param_specs
-    from repro.obs import MetricsRegistry, TickTimer, Tracer, set_tracer
-    from repro.runtime import (
-        BackgroundTuner, ChaosInjector, Server, StreamingEngine,
-    )
 
-    tracer = Tracer() if args.trace_out else None
-    if tracer is not None:
-        # process-wide: tuner trials, search stages, background jobs, and
-        # fleet calls all land on the same flight recorder as the engine
-        set_tracer(tracer)
-    registry = MetricsRegistry() if args.metrics_out else None
-
-    cfg = get_config(args.arch, smoke=not args.full)
-    params = init_params(jax.random.PRNGKey(0), param_specs(cfg))
     if args.stream and args.chaos_seed is not None:
         # the overload trace: the bursty mix plus deadlines and priorities,
         # so the hardened paths (timeout, shed, preempt) actually fire
-        requests = adversarial_trace(
+        return adversarial_trace(
             cfg, args.requests, seed=args.chaos_seed,
             scale=1.0 if args.full else 0.25,
             burst_size=args.burst_size, burst_gap_s=args.burst_gap,
             deadline_ttl_s=args.deadline or 0.5,
         )
-    elif args.trace == "bursty":
+    if args.trace == "bursty":
         # smoke configs get a scaled-down trace: full-length decodes dominate
         # a CI smoke run without exercising anything extra
-        requests = bursty_open_loop_trace(
+        return bursty_open_loop_trace(
             cfg, args.requests, scale=1.0 if args.full else 0.25,
             burst_size=args.burst_size, burst_gap_s=args.burst_gap,
         )
-    elif args.trace == "mixed":
-        requests = mixed_traffic_trace(cfg, args.requests)
-    else:
-        requests = synthetic_requests(
-            cfg, args.requests, args.prompt_len, args.new_tokens
-        )
+    if args.trace == "mixed":
+        return mixed_traffic_trace(cfg, args.requests)
+    return synthetic_requests(
+        cfg, args.requests, args.prompt_len, args.new_tokens
+    )
 
+
+def make_stream_engine(
+    cfg: Any, params: Any, requests: Sequence[Any], args: argparse.Namespace,
+    tuner: Any = None, tracer: Any = None,
+) -> Any:
+    """The StreamingEngine ``args`` configures, with its ChaosInjector (if
+    ``--chaos-seed``) as ``engine.chaos``."""
+    from repro.core import TuningDB
+    from repro.obs import TickTimer
+    from repro.runtime import ChaosInjector, StreamingEngine
+
+    max_len = args.max_len or max(
+        len(r.prompt) + r.max_new_tokens for r in requests
+    )
+    chaos = (
+        ChaosInjector(
+            seed=args.chaos_seed,
+            step_fault_rate=args.chaos_fault_rate,
+            squeeze_rate=0.1,
+            delay_rate=0.1,
+        )
+        if args.chaos_seed is not None else None
+    )
+    return StreamingEngine(
+        cfg,
+        params,
+        n_blocks=args.blocks,
+        max_len=max_len,
+        tuning_db=TuningDB(args.tuning_db) if args.tuning_db else None,
+        background_tuner=tuner,
+        inline_tune=args.inline_tune,
+        device_key=args.device_key,
+        hardened=not args.unhardened,
+        queue_limit=args.queue_limit,
+        shed_policy=args.shed_policy,
+        default_ttl_s=args.deadline,
+        chaos=chaos,
+        timer=TickTimer(args.tick_timer) if args.tick_timer else None,
+        tracer=tracer,
+    )
+
+
+def stream_faults(
+    engine: Any, requests: Sequence[Any], tuner: Any = None,
+    drained: bool = True,
+) -> List[str]:
+    """Why a stream run failed; empty when it did not.
+
+    Every request must retire exactly once (the hardened drain contract).
+    Unless the engine ran under a ChaosInjector, an ``error`` retirement
+    and a background-tuning failure or missed drain are faults too.
+    """
+    faults: List[str] = []
+    if engine.hardened:
+        unique_rids = {r.rid for r in requests}
+        missing = sorted(unique_rids - set(engine.results))
+        if missing:
+            faults.append(f"drain incomplete — {len(missing)} requests "
+                          f"never retired: {missing[:8]}")
+    if engine.chaos is not None:
+        return faults
+    for rid, res in sorted(engine.results.items()):
+        if res.status == "error":
+            faults.append(f"request {rid} retired error: {res.detail}")
+    if tuner is not None:
+        if not drained:
+            faults.append("background tuning did not drain")
+        for label, err in tuner.errors:
+            faults.append(f"background tuning failed for {label}: {err!r}")
+    return faults
+
+
+def load_model(args: argparse.Namespace) -> Tuple[Any, Any]:
+    """The config ``args`` names and its random parameters (``--seed``)."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import init_params, param_specs
+
+    cfg = get_config(args.arch, smoke=not args.full)
+    return cfg, init_params(jax.random.PRNGKey(args.seed), param_specs(cfg))
+
+
+def make_background_tuner(args: argparse.Namespace) -> Any:
+    """The BackgroundTuner ``--background-tune`` asks for, else None."""
+    from repro.fleet import FleetCoordinator
+    from repro.runtime import BackgroundTuner
+
+    if not args.background_tune:
+        return None
     fleet = (
         FleetCoordinator(workers=args.fleet_workers, backend="thread")
         if args.fleet_workers else None
     )
-    tuner = BackgroundTuner(fleet=fleet) if args.background_tune else None
+    return BackgroundTuner(fleet=fleet)
 
-    if args.stream:
-        max_len = args.max_len or max(
-            len(r.prompt) + r.max_new_tokens for r in requests
+
+def run_stream(
+    cfg: Any, params: Any, args: argparse.Namespace, tuner: Any = None,
+    tracer: Any = None, registry: Any = None,
+) -> Tuple[Any, List[Any], List[str]]:
+    """Serve the trace on a StreamingEngine, report it, drain the tuner.
+
+    Returns ``(engine, requests, faults)``; ``faults`` is
+    :func:`stream_faults` of the run.
+    """
+    from repro.obs import MetricsRegistry, set_tracer
+
+    requests = make_requests(cfg, args)
+    engine = make_stream_engine(cfg, params, requests, args, tuner, tracer)
+    chaos = engine.chaos
+    out = engine.serve(requests)
+    s = engine.stats
+    print(
+        f"served {len(out)} requests, {s.tokens_out} tokens, "
+        f"{s.tok_per_s:.1f} tok/s "
+        f"({s.prefill_steps} prefill / {s.decode_steps} decode steps, "
+        f"peak in-flight {s.peak_in_flight})"
+    )
+    # every stat object flows through the one registry pipe — the
+    # report below and --metrics-out render the same source of truth
+    registry = registry or MetricsRegistry()
+    registry.register_stats("engine", s, help="streaming-engine stats")
+    if chaos is not None:
+        registry.register_stats(
+            "chaos", chaos.stats, help="chaos-injector stats"
         )
-        chaos = (
-            ChaosInjector(
-                seed=args.chaos_seed,
-                step_fault_rate=args.chaos_fault_rate,
-                squeeze_rate=0.1,
-                delay_rate=0.1,
+
+    def _retired(reg):
+        for status in ("ok", "timed_out", "shed", "error"):
+            n = sum(
+                1 for r in engine.results.values() if r.status == status
             )
-            if args.chaos_seed is not None else None
-        )
-        engine = StreamingEngine(
-            cfg,
-            params,
-            n_blocks=args.blocks,
-            max_len=max_len,
-            tuning_db=TuningDB(args.tuning_db) if args.tuning_db else None,
-            background_tuner=tuner,
-            inline_tune=args.inline_tune,
-            device_key=args.device_key,
-            hardened=not args.unhardened,
-            queue_limit=args.queue_limit,
-            shed_policy=args.shed_policy,
-            default_ttl_s=args.deadline,
-            chaos=chaos,
-            timer=TickTimer(args.tick_timer) if args.tick_timer else None,
-            tracer=tracer,
-        )
-        out = engine.serve(requests)
-        s = engine.stats
+            reg.gauge(
+                "engine_retired", help="terminal request statuses"
+            ).set(n, status=status)
+
+    registry.register_collector(_retired)
+    print(registry.report(title="stream metrics"))
+    print(f"traffic classes: {', '.join(engine.traffic_classes_seen) or '-'}")
+    print(f"hot-path tuning evaluations: {engine.hot_path_cost_evaluations}")
+    drained = True
+    if tuner is not None:
+        drained = tuner.drain(timeout=300)
+        tuner.stop()
         print(
-            f"served {len(out)} requests, {s.tokens_out} tokens, "
-            f"{s.tok_per_s:.1f} tok/s "
-            f"({s.prefill_steps} prefill / {s.decode_steps} decode steps, "
-            f"peak in-flight {s.peak_in_flight})"
+            f"background-tuned classes: "
+            f"{', '.join(tuner.tuned_labels) or '-'} "
+            f"({tuner.background_evaluations} evaluations off the hot path)"
         )
-        # every stat object flows through the one registry pipe — the
-        # report below and --metrics-out render the same source of truth
-        registry = registry or MetricsRegistry()
-        registry.register_stats("engine", s, help="streaming-engine stats")
-        if chaos is not None:
-            registry.register_stats(
-                "chaos", chaos.stats, help="chaos-injector stats"
-            )
+        sched = engine.tuned_scheduler_classes
+        print(f"tuned scheduler classes: {', '.join(sched) or '-'}")
+    if args.metrics_out:
+        registry.write(args.metrics_out)
+        print(f"metrics written to {args.metrics_out}")
+    if tracer is not None:
+        set_tracer(None)
+        tracer.write(args.trace_out)
+        print(f"trace written to {args.trace_out} "
+              f"({tracer.emitted} events, {tracer.dropped} dropped)")
+    return engine, requests, stream_faults(engine, requests, tuner, drained)
 
-        def _retired(reg):
-            for status in ("ok", "timed_out", "shed", "error"):
-                n = sum(
-                    1 for r in engine.results.values() if r.status == status
-                )
-                reg.gauge(
-                    "engine_retired", help="terminal request statuses"
-                ).set(n, status=status)
 
-        registry.register_collector(_retired)
-        print(registry.report(title="stream metrics"))
-        if not args.unhardened:
-            unique_rids = {r.rid for r in requests}
-            if set(engine.results) != unique_rids:
-                missing = sorted(unique_rids - set(engine.results))
-                print(f"ERROR: drain incomplete — {len(missing)} requests "
-                      f"never retired: {missing[:8]}")
-                sys.exit(1)
-        print(f"traffic classes: {', '.join(engine.traffic_classes_seen) or '-'}")
-        print(f"hot-path tuning evaluations: {engine.hot_path_cost_evaluations}")
-        if tuner is not None:
-            drained = tuner.drain(timeout=300)
-            tuner.stop()
-            print(
-                f"background-tuned classes: "
-                f"{', '.join(tuner.tuned_labels) or '-'} "
-                f"({tuner.background_evaluations} evaluations off the hot path)"
-            )
-            sched = engine.tuned_scheduler_classes
-            print(f"tuned scheduler classes: {', '.join(sched) or '-'}")
-            if not drained:
-                print("WARNING: background tuning did not drain within 300s")
-            for label, err in tuner.errors:
-                print(f"WARNING: background tuning failed for {label}: {err!r}")
-        if args.metrics_out:
-            registry.write(args.metrics_out)
-            print(f"metrics written to {args.metrics_out}")
-        if tracer is not None:
-            set_tracer(None)
-            tracer.write(args.trace_out)
-            print(f"trace written to {args.trace_out} "
-                  f"({tracer.emitted} events, {tracer.dropped} dropped)")
-        return
+def run_static(
+    cfg: Any, params: Any, args: argparse.Namespace, tuner: Any = None,
+    tracer: Any = None, registry: Any = None,
+) -> List[str]:
+    """Serve the trace on the static-batch Server; returns its faults."""
+    from repro.core import TuningDB
+    from repro.fleet import DriftMonitor
+    from repro.obs import MetricsRegistry, set_tracer
+    from repro.runtime import Server
 
+    requests = make_requests(cfg, args)
     drift = (
         DriftMonitor(background=tuner, factor=args.drift_factor)
         if args.drift_factor else None
@@ -345,15 +420,16 @@ def main() -> None:
           f"{server.stats.decode_tok_per_s:.1f} tok/s")
     print(f"traffic classes: {', '.join(server.traffic_classes_seen) or '-'}")
     print(f"hot-path tuning evaluations: {server.hot_path_cost_evaluations}")
+    faults: List[str] = []
     if tuner is not None:
         drained = tuner.drain(timeout=300)
         tuner.stop()
         print(f"background-tuned classes: {', '.join(tuner.tuned_labels) or '-'} "
               f"({tuner.background_evaluations} evaluations off the hot path)")
         if not drained:
-            print("WARNING: background tuning did not drain within 300s")
+            faults.append("background tuning did not drain")
         for label, err in tuner.errors:
-            print(f"WARNING: background tuning failed for {label}: {err!r}")
+            faults.append(f"background tuning failed for {label}: {err!r}")
     if drift is not None and drift.transitions:
         kinds = ", ".join(kind for _, kind in drift.transitions)
         print(f"drift transitions: {kinds}")
@@ -369,7 +445,35 @@ def main() -> None:
         tracer.write(args.trace_out)
         print(f"trace written to {args.trace_out} "
               f"({tracer.emitted} events, {tracer.dropped} dropped)")
+    return faults
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+
+    from repro.obs import MetricsRegistry, Tracer, set_tracer
+
+    tracer = Tracer() if args.trace_out else None
+    if tracer is not None:
+        # process-wide: tuner trials, search stages, background jobs, and
+        # fleet calls all land on the same flight recorder as the engine
+        set_tracer(tracer)
+    registry = MetricsRegistry() if args.metrics_out else None
+
+    cfg, params = load_model(args)
+    tuner = make_background_tuner(args)
+    if args.stream:
+        _, _, faults = run_stream(cfg, params, args, tuner, tracer, registry)
+    else:
+        faults = run_static(cfg, params, args, tuner, tracer, registry)
+    for fault in faults:
+        print(f"ERROR: {fault}")
+    if faults:
+        sys.exit(1)
 
 
 if __name__ == "__main__":
+    from repro.launch import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -3,10 +3,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --steps 100 --ckpt-dir /tmp/run1 [--smoke]
 
-On this host the full configs are CPU-prohibitive; --smoke (default) uses
-the reduced config.  On a real TPU slice the same entry point shards
-params/opt-state with the tuned sharding rule (see launch/dryrun.py for the
-rule selection machinery).
+Without ``--full`` the reduced smoke config trains (the full configs are
+prohibitive on a CPU).  The trainer runs on one device: it builds no mesh
+and shards nothing (the sharding rules of distributed/sharding.py are only
+exercised by launch/dryrun.py).  The train step donates its params and
+optimizer state, so a full config's step holds one copy of them.
 
 ``--joint-tune`` runs whole-program joint AT (docs/program.md) before the
 loop: the (microbatch degree × remat directive) composition is searched
@@ -15,9 +16,10 @@ DB under the program fingerprint (``--tuning-db`` makes it survive runs),
 and hot-applies through ``region.select``.
 """
 import argparse
+from typing import Optional, Sequence
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
@@ -25,6 +27,8 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--full", action="store_true", help="full (non-smoke) config")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the parameters and the data stream")
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument(
         "--joint-tune", action="store_true",
@@ -46,8 +50,11 @@ def main() -> None:
         help="namespace DB entries (and the joint-program fingerprint) "
              "under the host DeviceFingerprint (docs/fleet.md)",
     )
-    args = ap.parse_args()
+    return ap
 
+
+def make_trainer(args: argparse.Namespace):
+    """The Trainer and dataset ``args`` configure."""
     from repro.configs import get_config
     from repro.core import TuningDB
     from repro.data import SyntheticLMDataset
@@ -62,11 +69,19 @@ def main() -> None:
             total_steps=args.steps, ckpt_dir=args.ckpt_dir,
             n_microbatches=args.microbatches,
             joint_tune=args.joint_tune, joint_cap=args.joint_cap,
-            joint_k=args.joint_k, device_key=args.device_key,
+            joint_k=args.joint_k, device_key=args.device_key, seed=args.seed,
         ),
         tuning_db=TuningDB(args.tuning_db) if args.tuning_db else None,
     )
-    ds = SyntheticLMDataset(cfg, global_batch=args.batch, seq_len=args.seq)
+    ds = SyntheticLMDataset(
+        cfg, global_batch=args.batch, seq_len=args.seq, seed=args.seed
+    )
+    return trainer, ds
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = build_parser().parse_args(argv)
+    trainer, ds = make_trainer(args)
     hist = trainer.run(ds)
     print(f"final loss: {hist['loss'][-1]:.4f} after {len(hist['loss'])} steps")
     if trainer.joint_result is not None:
@@ -78,4 +93,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # placeholder devices only: never the chip
 
 """Before-execution AT of a full training/serving cell through the FIBER
 tuner — the paper's §IV procedure ("user fixes BP; measure all candidates;

@@ -164,7 +164,9 @@ class Trainer:
         # joint_tune replaces the pin with a whole-program search whose cost
         # is the measured full step.  The remat directive lives in a mutable
         # cell so a joint winner hot-applies without rebuilding the region
-        # (the region is invalidated instead, see _on_joint_apply).
+        # (the region is invalidated instead, see _on_joint_apply).  The
+        # live step donates params and optimizer state — the loop only ever
+        # keeps the step's outputs — so a step holds one copy of them.
         degrees = tuple(loop_cfg.microbatch_candidates)
         self._step_remat = cfg.remat
         bp = BasicParams.make(arch=cfg.name, kind="train_runtime", micro=degrees)
@@ -182,7 +184,8 @@ class Trainer:
                         make_train_step(
                             cfg.with_(remat=self._step_remat), opt_cfg,
                             pt["n_micro"],
-                        )
+                        ),
+                        donate_argnums=(0, 1),
                     ),
                 ),
                 shape_class=lambda *a, **k: bp,
@@ -239,6 +242,7 @@ class Trainer:
         ]
 
         def build(assignment):
+            # no donation: the measured thunk re-feeds the same arrays
             step = jax.jit(
                 make_train_step(
                     cfg.with_(remat=assignment["remat"]["remat"]),

@@ -63,6 +63,30 @@ def test_default_interpret_matches_backend():
     assert default_interpret() == (jax.default_backend() == "cpu")
 
 
+class _FakeDevice:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_detect_tpu_by_exact_device_kind(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeDevice("TPU v5 lite")] * 4)
+    arch = detect("tpu")
+    assert (arch.name, arch.core_count) == ("tpu_v5e", 4)
+    assert arch.vmem_budget() * 2 <= arch.vmem_limit_bytes() < arch.vmem_bytes
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "TPU v5e", ""])
+def test_detect_unknown_tpu_kind_raises(monkeypatch, kind):
+    """A TPU missing from the table is an error, never another chip's VMEM."""
+    import jax
+
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeDevice(kind)])
+    with pytest.raises(ValueError, match="no ArchSpec for TPU device_kind"):
+        detect("tpu")
+
+
 # ---------------------------------------------------------------------------
 # emitted spaces
 # ---------------------------------------------------------------------------

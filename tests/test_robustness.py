@@ -101,6 +101,71 @@ def test_control_flow_exceptions_still_propagate():
     assert db.quarantined(BP) == {}  # control flow, not a broken candidate
 
 
+@pytest.mark.parametrize("workers", [1, 2])
+def test_prescreen_failure_scores_inf(workers):
+    from repro.core.cost import score_points_concurrently
+
+    def score(p):
+        if p["i"] == 1:
+            raise ValueError("refused by the compiler")
+        return float(p["i"])
+
+    points = [{"i": i} for i in range(3)]
+    assert score_points_concurrently(score, points, max_workers=workers) == [
+        0.0, math.inf, 2.0,
+    ]
+
+
+def test_search_completed_counts_prescreen_exclusions():
+    """A candidate whose prescreen raises is excluded and counted in the
+    search_completed event, so a caller that demands a space of compilable
+    candidates only (chip_smoke.py) can refuse the search."""
+    import jax.numpy as jnp
+
+    from repro.core import AutotunedOp, KernelSpec
+
+    space = ParamSpace([PerfParam("i", (0, 1, 2, 3))])
+
+    def prescreen_factory(region, bp, args, kwargs):
+        def score(p):
+            if p["i"] == 1:
+                raise ValueError("refused by the compiler")
+            return float(p["i"])
+
+        return score
+
+    spec = KernelSpec(
+        "excluded_toy",
+        make_region=lambda bp: ATRegion("excluded_toy", space,
+                                        lambda p: (lambda x: x)),
+        shape_class=lambda x: BasicParams.make(kernel="excluded_toy", n=4),
+        cost_factory=lambda r, b, a, k: (lambda p: float(p["i"])),
+        prescreen_factory=prescreen_factory,
+    )
+    db = TuningDB()
+    state = AutotunedOp(spec, db=db, prescreen_k=2).resolve(jnp.ones(4))
+    (done,) = [e for e in db.events(state.bp) if e["kind"] == "search_completed"]
+    assert done["prescreen_excluded"] == 1
+    assert state.region.selected == {"i": 0}
+    assert db.quarantined(state.bp) == {}  # excluded before any measurement
+
+
+class _FailsToBlock:
+    """An output whose device work fails when it is waited on."""
+
+    def block_until_ready(self):
+        raise RuntimeError("device fault")
+
+
+@pytest.mark.parametrize("cls", ["WallClockCost", "AdaptiveWallClockCost"])
+def test_wallclock_cost_propagates_errors_from_blocking(cls):
+    import repro.core.cost as cost_mod
+
+    cost = getattr(cost_mod, cls)(lambda p: _FailsToBlock)
+    with pytest.raises(RuntimeError, match="device fault"):
+        cost({"i": 0})
+
+
 def test_record_best_refuses_non_finite():
     db = TuningDB()
     with pytest.raises(ValueError, match="never become a final best"):
