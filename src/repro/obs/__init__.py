@@ -22,7 +22,15 @@ from .metrics import (
     parse_prometheus,
     snapshot_stats,
 )
-from .trace import TickTimer, Tracer, current_tracer, set_tracer, use_tracer
+from .trace import (
+    DeferredRegion,
+    Region,
+    TickTimer,
+    Tracer,
+    current_tracer,
+    set_tracer,
+    use_tracer,
+)
 
 __all__ = [
     "Counter",
@@ -31,6 +39,8 @@ __all__ = [
     "MetricsRegistry",
     "parse_prometheus",
     "snapshot_stats",
+    "DeferredRegion",
+    "Region",
     "TickTimer",
     "Tracer",
     "current_tracer",

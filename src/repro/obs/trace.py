@@ -12,13 +12,16 @@ Design constraints (docs/observability.md):
   guard lives only on slow paths.  The ``bench_dispatch`` >=10x gate and the
   ``obs_overhead`` <=2% gate in ``benchmarks/`` enforce this.
 * **Deterministic export** — the clock is injectable (the engine passes its
-  virtual clock / a :class:`TickTimer`), timestamps are rounded to integer
-  microseconds, and :meth:`Tracer.to_json` sorts events and track-ids
-  canonically so the same run produces byte-identical trace files.
+  measurement timer, a :class:`TickTimer` in tests), timestamps are rounded
+  to integer microseconds, and :meth:`Tracer.to_json` sorts events and
+  track-ids canonically so the same run produces byte-identical trace files.
+* **One span, both traces** — a :class:`Region` is also a
+  ``jax.profiler.TraceAnnotation``, so it lands on the host plane of the
+  profiler's trace beside the device operations; ``jax`` is imported only
+  when the first region opens.
 
-Span timestamps are *seconds* at the API (matching ``time.perf_counter``
-and the engine's virtual ``now``); export converts to the integer
-microseconds Perfetto expects.
+Span timestamps are *seconds* at the API (matching ``time.perf_counter``);
+export converts to the integer microseconds Perfetto expects.
 """
 from __future__ import annotations
 
@@ -27,9 +30,12 @@ import threading
 import time
 from collections import deque
 from contextlib import contextmanager
+from functools import lru_cache
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
 __all__ = [
+    "DeferredRegion",
+    "Region",
     "Tracer",
     "TickTimer",
     "current_tracer",
@@ -86,7 +92,8 @@ class Tracer:
     * :meth:`span` — context manager stamping ``clock()`` at enter/exit
       (wall-time instrumentation: tuner trials, fleet RPCs, background jobs).
     * :meth:`complete` / :meth:`instant` — explicit timestamps for code that
-      owns its own clock (the streaming engine's virtual ``now``).
+      owns its own clock (the streaming engine's measurement timer, through
+      :class:`Region`).
     """
 
     def __init__(
@@ -217,6 +224,116 @@ class Tracer:
     def write(self, path: str) -> None:
         with open(path, "w") as f:
             f.write(self.to_json())
+
+
+# -- regions: one span written into both traces -----------------------------
+
+
+@lru_cache(maxsize=None)
+def _annotation_type():
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+class Region:
+    """A span written once into two traces: the profiler's and a
+    :class:`Tracer`'s.
+
+    Making a region starts it: it enters a ``jax.profiler.TraceAnnotation``
+    named ``name`` (carrying ``attrs`` as metadata), so the span lands on
+    the host plane of a running ``jax.profiler`` trace, on the clock of the
+    device operations, then reads ``clock`` for ``t0``.  :meth:`close` reads
+    ``clock`` for ``t1``, leaves the annotation and, when ``tracer`` (or, if
+    it is None, the installed tracer) exists, emits
+    ``tracer.complete(name, t0, t1, ...)``.  So the annotation encloses
+    ``[t0, t1]``, and the region reads its clock at those two points only.
+
+    Use it as a context manager around a block, or make it and call
+    :meth:`close` later for a span that is not lexical (a request's wait in
+    a queue).  :meth:`close` is :meth:`end` (the clock read and the
+    annotation's exit) then :meth:`emit` (the tracer's event).  A region
+    dropped without :meth:`close` emits nothing to the tracer; the profiler
+    records its annotation when it is collected.  A region opened while the
+    profiler is off makes no annotation (as a ``TraceAnnotation`` made then
+    would record nothing), so with no tracer either it costs two clock
+    reads.
+    """
+
+    __slots__ = ("name", "tracer", "clock", "cat", "track", "attrs", "t0",
+                 "t1", "_ann")
+
+    def __init__(
+        self,
+        name: str,
+        tracer: Any = None,
+        clock: Callable[[], float] = time.perf_counter,
+        cat: str = "",
+        track: Optional[str] = None,
+        **attrs: Any,
+    ):
+        self.name = name
+        self.tracer = tracer if tracer is not None else _ACTIVE
+        self.clock = clock
+        self.cat = cat
+        self.track = track
+        self.attrs = attrs
+        self.t1: Optional[float] = None
+        # an annotation made while the profiler records nothing would record
+        # nothing itself, so none is made
+        annotation = _annotation_type()
+        self._ann = annotation(name, **attrs) if annotation.is_enabled() else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = clock()
+
+    def close(self, **attrs: Any) -> float:
+        """End the span, adding ``attrs`` to both traces; returns its
+        duration on ``clock``."""
+        duration = self.end(**attrs)
+        self.emit()
+        return duration
+
+    def end(self, **attrs: Any) -> float:
+        """End the span on ``clock`` and in the profiler's trace, adding
+        ``attrs``; the tracer's event waits for :meth:`emit`.  Returns the
+        duration on ``clock``."""
+        self.t1 = self.clock()
+        if attrs:
+            self.attrs.update(attrs)
+        if self._ann is not None:
+            if attrs:
+                self._ann.set_metadata(**attrs)
+            self._ann.__exit__(None, None, None)
+        return self.t1 - self.t0
+
+    def emit(self) -> None:
+        """Write the ended span to the tracer, if there is one."""
+        if self.tracer is not None:
+            self.tracer.complete(self.name, self.t0, self.t1, cat=self.cat,
+                                 track=self.track, **self.attrs)
+
+    def __enter__(self) -> "Region":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.close()
+
+
+class DeferredRegion(Region):
+    """A :class:`Region` that leaves its tracer event to a later
+    :meth:`~Region.emit`: leaving the block only ends it (:meth:`~Region.end`).
+
+    A loop that waits on the device writes its regions' events while it
+    waits, not between one device step and the next, where every
+    microsecond of host work is device idle time.  The profiler's
+    annotation still ends with the block.
+    """
+
+    __slots__ = ()
+
+    def __exit__(self, *exc: Any) -> None:
+        self.end()
 
 
 # -- process-global tracer (the instrumentation guard) ----------------------

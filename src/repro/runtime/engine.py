@@ -102,7 +102,7 @@ from repro.core import (
 )
 from repro.core.autotuned import OpState
 from repro.data.pipeline import ServingRequest
-from repro.obs.trace import current_tracer
+from repro.obs.trace import DeferredRegion, Region, current_tracer
 from repro.distributed.sharding import mesh_bp_entries
 from repro.models import cache_batch_axis, decode_fn, init_cache, prefill_fn
 from repro.models.config import ModelConfig
@@ -290,6 +290,16 @@ class StreamStats:
     preempted: int = 0           # KV-block evictions for priority admissions
     step_faults: int = 0         # prefill/decode steps that raised
     knob_faults: int = 0         # scheduler-knob resolutions that raised
+    # the serve loop on the measurement timer, summed over its regions
+    iterations: int = 0          # passes of the serve loop (engine.iter)
+    host_s: float = 0.0          # engine.iter time outside *.device regions
+    schedule_s: float = 0.0      # engine.schedule
+    prepare_s: float = 0.0       # engine.prefill.prepare + decode.prepare
+    commit_s: float = 0.0        # engine.prefill.commit + decode.commit
+    queue_wait_s: float = 0.0    # engine.queue: admission to first prefill
+    queue_waits: int = 0
+    decode_rows_live: int = 0    # requests in each engine.decode.device
+    decode_rows_run: int = 0     # the pow2 bucket each one ran as
 
     @property
     def tok_per_s(self) -> float:
@@ -311,8 +321,6 @@ class StreamStats:
             "decode_calls": self.decode_calls,
             "prefill_s": self.prefill_s,
             "decode_s": self.decode_s,
-            "idle_s": self.idle_s,
-            "makespan_s": self.makespan_s,
             "peak_in_flight": self.peak_in_flight,
             "requests_finished": len(self.finish_s),
             "timeouts": self.timeouts,
@@ -322,9 +330,15 @@ class StreamStats:
             "preempted": self.preempted,
             "step_faults": self.step_faults,
             "knob_faults": self.knob_faults,
-            "tok_per_s": self.tok_per_s,
-            "ttft_p50_s": self.ttft_percentile(50),
-            "ttft_p99_s": self.ttft_percentile(99),
+            "iterations": self.iterations,
+            "host_s": self.host_s,
+            "schedule_s": self.schedule_s,
+            "prepare_s": self.prepare_s,
+            "commit_s": self.commit_s,
+            "queue_wait_s": self.queue_wait_s,
+            "queue_waits": self.queue_waits,
+            "decode_rows_live": self.decode_rows_live,
+            "decode_rows_run": self.decode_rows_run,
         }
 
 
@@ -352,6 +366,8 @@ class _Waiting:
     resume: List[int] = field(default_factory=list)
     preemptions: int = 0
     deadline: Optional[float] = None  # absolute virtual-clock deadline
+    # engine.queue, open from admission until the first prefill serves it
+    queue: Optional[Region] = None
 
 
 @dataclass
@@ -444,12 +460,18 @@ class StreamingEngine:
         self.max_preemptions = int(max_preemptions)
         self.watchdog_limit = int(watchdog_limit)
         self.chaos = chaos
-        # observability: ``timer`` is the *measurement* clock (step wall
-        # times feeding the virtual clock) — inject e.g. a TickTimer for
-        # byte-identical deterministic traces; ``tracer`` pins a Tracer to
-        # this engine (falls back to the process-wide current_tracer())
+        # observability: ``timer`` is the *measurement* clock — it stamps
+        # every engine span and event, and the step times it measures feed
+        # the virtual clock; inject e.g. a TickTimer for byte-identical
+        # deterministic traces.  ``tracer`` pins a Tracer to this engine
+        # (falls back to the process-wide current_tracer())
         self._timer = timer if timer is not None else time.perf_counter
         self.tracer = tracer
+        # the regions of the current serve-loop pass (its id, its children),
+        # and those of ended passes whose events wait for the next device step
+        self._iter = 0
+        self._spans: List[Region] = []
+        self._ended: List[Region] = []
         self.cache = PagedKVCache(cfg, n_blocks, self.max_len)
         self.degree = DegreeController(max_degree=max(2, n_blocks))
         self.stats = StreamStats()
@@ -461,11 +483,14 @@ class StreamingEngine:
         # raw jitted primitives (shared by hot path, candidates, and the
         # scheduler's shadow replay); counted wrappers feed the stats the
         # regression tests assert on.  capacity is pinned so prefilled group
-        # caches always match the pool's row layout.
+        # caches always match the pool's row layout.  Named functions, so
+        # the profiler names the programs jit_engine_prefill/_decode.
         cap = self.max_len
-        self._prefill_raw = jax.jit(
-            lambda p, b: prefill_fn(p, b, cfg, capacity=cap)
-        )
+
+        def engine_prefill(p, b):
+            return prefill_fn(p, b, cfg, capacity=cap)
+
+        self._prefill_raw = jax.jit(engine_prefill)
         self._decode_raw = jax.jit(_make_decode_rows(cfg))
 
         def counted_prefill(p, b):
@@ -494,6 +519,68 @@ class StreamingEngine:
     def _tr(self):
         """Active tracer for engine events (pinned beats process-global)."""
         return self.tracer if self.tracer is not None else current_tracer()
+
+    def _region(self, name: str, **attrs: Any) -> Region:
+        """Open a child region of the current serve-loop pass, stamped by
+        the measurement timer; its event and its share of the stats wait
+        for :meth:`_emit_regions`."""
+        r = DeferredRegion(name, self.tracer, self._timer, cat="engine",
+                           track="engine", iter=self._iter, **attrs)
+        self._spans.append(r)
+        return r
+
+    def _close_iter(self, it: Region) -> None:
+        """End one pass's ``engine.iter``; it follows its children into the
+        regions :meth:`_emit_regions` writes."""
+        it.end()
+        self.stats.iterations += 1
+        self._spans.append(it)
+        self._ended += self._spans
+        self._spans = []
+
+    def _emit_regions(self) -> None:
+        """Write the ended passes' regions to the tracer and add them to the
+        stats: host time is a pass outside its device regions.  Called
+        between a device step's dispatch and its ``block_until_ready``, so
+        the work overlaps the device's and adds nothing to the host time
+        between two steps, and when a serve ends."""
+        ended = self._ended
+        if not ended:
+            return
+        self._ended = []
+        s = self.stats
+        device = 0.0
+        for r in ended:
+            r.emit()
+            name = r.name
+            d = r.t1 - r.t0
+            if name == "engine.iter":  # the last region of its pass
+                s.host_s += d - device
+                device = 0.0
+            elif name.endswith(".device"):
+                device += d
+                if name == "engine.decode.device":
+                    s.decode_rows_live += r.attrs["batch"]
+                    s.decode_rows_run += r.attrs["bucket"]
+            elif name == "engine.schedule":
+                s.schedule_s += d
+            elif name.endswith(".prepare"):
+                s.prepare_s += d
+            elif name.endswith(".commit"):
+                s.commit_s += d
+
+    def _open_queue(self, rid: int) -> Region:
+        """``engine.queue`` of one admitted request; the first prefill that
+        serves it ends it (:meth:`_close_queue`)."""
+        return DeferredRegion("engine.queue", self.tracer, self._timer,
+                              cat="engine", track="engine", rid=rid)
+
+    def _close_queue(self, w: "_Waiting") -> None:
+        if w.queue is not None:
+            self.stats.queue_wait_s += w.queue.end(iter=self._iter)
+            self.stats.queue_waits += 1
+            self._spans.append(w.queue)
+            w.queue = None
 
     # -- registry ops --------------------------------------------------------
 
@@ -852,10 +939,10 @@ class StreamingEngine:
         )
         tr = self._tr()
         if tr is not None:
-            # exactly one terminal instant per admitted rid, on the virtual
-            # clock (the retire-uniqueness property test keys on this)
+            # exactly one terminal instant per admitted rid (the
+            # retire-uniqueness property test keys on this)
             tr.instant(
-                "engine.retire", t=now, cat="engine", track="engine",
+                "engine.retire", t=self._timer(), cat="engine", track="engine",
                 rid=rid, status=status, tokens=len(tokens),
             )
         self.cache.release(rid)
@@ -909,11 +996,11 @@ class StreamingEngine:
         tr = self._tr()
         if tr is not None:
             tr.instant(
-                "engine.admit", t=now, cat="engine", track="engine",
+                "engine.admit", t=self._timer(), cat="engine", track="engine",
                 rid=rid, plen=plen, max_new_tokens=mnt,
-                queue_wait_s=round(max(0.0, now - float(r.arrival_s)), 9),
             )
-        waiting.append(_Waiting(req=r, deadline=self._deadline_of(r)))
+        waiting.append(_Waiting(req=r, deadline=self._deadline_of(r),
+                                queue=self._open_queue(rid)))
 
     def _expire_deadlines(
         self,
@@ -970,7 +1057,6 @@ class StreamingEngine:
 
     def _maybe_preempt(
         self, waiting: List[_Waiting], active: Dict[int, _Active],
-        now: float = 0.0,
     ) -> bool:
         """Evict the lowest-priority in-flight request when the pool is
         exhausted and a strictly higher-priority admission is blocked.  The
@@ -995,7 +1081,7 @@ class StreamingEngine:
         tr = self._tr()
         if tr is not None:
             tr.instant(
-                "engine.preempt", t=now, cat="engine", track="engine",
+                "engine.preempt", t=self._timer(), cat="engine", track="engine",
                 rid=rid, priority=int(victim.req.priority),
                 preemptions=victim.preemptions + 1,
             )
@@ -1075,6 +1161,19 @@ class StreamingEngine:
         out: Dict[int, List[int]] = {}
         if not reqs:
             return out
+        serve_region = Region("engine.serve", self.tracer, self._timer,
+                              cat="engine", track="engine", requests=len(reqs))
+        try:
+            self._serve_loop(reqs, out)
+        finally:
+            self._emit_regions()
+            serve_region.close(retired=len(self.results),
+                               tokens_out=self.stats.tokens_out)
+        return out
+
+    def _serve_loop(
+        self, reqs: List[ServingRequest], out: Dict[int, List[int]]
+    ) -> None:
         now = reqs[0].arrival_s
         t_start = now
         cursor = 0
@@ -1084,74 +1183,76 @@ class StreamingEngine:
         idle_iters = 0
 
         while cursor < len(reqs) or waiting or active:
-            while cursor < len(reqs) and reqs[cursor].arrival_s <= now:
-                r = reqs[cursor]
-                cursor += 1
-                if self.hardened:
-                    self._admit(r, seen, waiting, out, now)
-                else:
-                    waiting.append(_Waiting(req=r))
-            if self.chaos is not None:
-                self.chaos.tick(self.cache)
-            if self.hardened:
-                self._expire_deadlines(waiting, active, out, now)
-            if not waiting and not active:
-                if cursor < len(reqs):
-                    # nothing runnable: the open-loop clock jumps to the
-                    # next arrival instead of sleeping
-                    self.stats.idle_s += reqs[cursor].arrival_s - now
-                    now = reqs[cursor].arrival_s
-                    continue
-                break  # everything retired; chaos may still hold blocks
-            n_retired = len(self.results)
-            knobs = self._safe_knobs(waiting, active)
-            if self.hardened and self.queue_limit is not None:
-                policy = self.shed_policy or str(
-                    knobs.get("shed_policy", "reject-new")
-                )
-                self._shed(waiting, out, now, policy)
-            if self.hardened:
-                self._maybe_preempt(waiting, active, now)
+            self._iter = self.stats.iterations
+            it = DeferredRegion("engine.iter", self.tracer, self._timer,
+                                cat="engine", track="engine", iter=self._iter,
+                                waiting=len(waiting), active=len(active))
+            try:
+                with self._region("engine.schedule"):
+                    while cursor < len(reqs) and reqs[cursor].arrival_s <= now:
+                        r = reqs[cursor]
+                        cursor += 1
+                        if self.hardened:
+                            self._admit(r, seen, waiting, out, now)
+                        else:
+                            waiting.append(_Waiting(
+                                req=r, queue=self._open_queue(r.rid)))
+                    if self.chaos is not None:
+                        self.chaos.tick(self.cache)
+                    if self.hardened:
+                        self._expire_deadlines(waiting, active, out, now)
+                    if not waiting and not active:
+                        if cursor < len(reqs):
+                            # nothing runnable: the open-loop clock jumps to
+                            # the next arrival instead of sleeping
+                            self.stats.idle_s += reqs[cursor].arrival_s - now
+                            now = reqs[cursor].arrival_s
+                            continue
+                        break  # everything retired; chaos may still hold blocks
+                    n_retired = len(self.results)
+                    knobs = self._safe_knobs(waiting, active)
+                    if self.hardened and self.queue_limit is not None:
+                        policy = self.shed_policy or str(
+                            knobs.get("shed_policy", "reject-new")
+                        )
+                        self._shed(waiting, out, now, policy)
+                    if self.hardened:
+                        self._maybe_preempt(waiting, active)
+                    group = self._pick_group(waiting, active, knobs)
 
-            progressed = False
-            group = self._pick_group(waiting, active, knobs)
-            if group:
-                now = self._prefill_step(group, active, waiting, out, now)
-                progressed = True
-            for _ in range(int(knobs["interleave"])):
-                if not active:
-                    break
-                now = self._decode_step(active, out, now)
-                progressed = True
-            if len(self.results) > n_retired:
-                progressed = True  # sheds/timeouts/errors are retirements
-            self.stats.peak_in_flight = max(
-                self.stats.peak_in_flight, len(active)
-            )
-            if progressed:
-                idle_iters = 0
-            else:
-                if not self.hardened:
-                    # waiting but no admission room and nothing decoding can
-                    # only mean a stuck ceiling; active==∅ implies room ≥ 1
-                    raise RuntimeError("scheduler stalled: no admissible work")
-                idle_iters += 1
-                if idle_iters > self.watchdog_limit:
-                    raise EngineStalled(
-                        self._state_dump(waiting, active, now, idle_iters)
-                    )
-                now = self._idle_advance(now, reqs, cursor, waiting, active)
+                progressed = False
+                if group:
+                    now = self._prefill_step(group, active, waiting, out, now)
+                    progressed = True
+                for _ in range(int(knobs["interleave"])):
+                    if not active:
+                        break
+                    now = self._decode_step(active, out, now)
+                    progressed = True
+                if len(self.results) > n_retired:
+                    progressed = True  # sheds/timeouts/errors are retirements
+                self.stats.peak_in_flight = max(
+                    self.stats.peak_in_flight, len(active)
+                )
+                if progressed:
+                    idle_iters = 0
+                else:
+                    if not self.hardened:
+                        # waiting but no admission room and nothing decoding
+                        # can only mean a stuck ceiling; active==∅ implies
+                        # room ≥ 1
+                        raise RuntimeError("scheduler stalled: no admissible work")
+                    idle_iters += 1
+                    if idle_iters > self.watchdog_limit:
+                        raise EngineStalled(
+                            self._state_dump(waiting, active, now, idle_iters)
+                        )
+                    now = self._idle_advance(now, reqs, cursor, waiting, active)
+            finally:
+                self._close_iter(it)
         if self.chaos is not None:
             self.chaos.drain(self.cache)
         self.stats.makespan_s += now - t_start
-        tr = self._tr()
-        if tr is not None:
-            tr.complete(
-                "engine.serve", t_start, now, cat="engine", track="engine",
-                requests=len(reqs), retired=len(self.results),
-                tokens_out=self.stats.tokens_out,
-            )
-        return out
 
     # -- prefill -------------------------------------------------------------
 
@@ -1200,80 +1301,88 @@ class StreamingEngine:
     ) -> float:
         reqs = [w.req for w in group]
         plen = len(reqs[0].prompt)
-        batch = build_batch_inputs(self.cfg, reqs, plen)
-        pstate = self._resolve(self.prefill_op, self.params, batch)
-        label = pstate.traffic.label if pstate.traffic else "prefill"
-        if self.chaos is not None:
-            self.chaos.before_step("prefill", [r.rid for r in reqs])
-        t0 = self._timer()
-        with self.degree.region(label):
-            logits, cache = pstate.region(self.params, batch)
-            logits.block_until_ready()
-        dt = self._timer() - t0
+        with self._region("engine.prefill.prepare"):
+            batch = build_batch_inputs(self.cfg, reqs, plen)
+            pstate = self._resolve(self.prefill_op, self.params, batch)
+            label = pstate.traffic.label if pstate.traffic else "prefill"
+            if self.chaos is not None:
+                self.chaos.before_step("prefill", [r.rid for r in reqs])
+        for w in group:
+            self._close_queue(w)
+        # the device region's two timer reads bracket dispatch through
+        # block_until_ready: they are the step's dt, nothing else ticks
+        with self._region("engine.prefill.device", batch=len(reqs),
+                          plen=plen) as dev:
+            with self.degree.region(label):
+                logits, cache = pstate.region(self.params, batch)
+                self._emit_regions()  # while the device runs the step
+                logits.block_until_ready()
+        dt = dev.t1 - dev.t0
         self.stats.prefill_s += dt
         self.stats.prefill_steps += 1
-        t_v0 = now
         now += dt
         if self.chaos is not None:
             now += self.chaos.step_delay()
         tr = self._tr()
         if tr is not None:
+            # emitted as the step's tokens exist, before any commit work
             tr.complete(
-                "engine.prefill", t_v0, now, cat="engine", track="engine",
-                rids=[r.rid for r in reqs], batch=len(reqs), plen=plen,
-                label=label,
+                "engine.prefill", dev.t0, dev.t1, cat="engine",
+                track="engine", rids=[r.rid for r in reqs], batch=len(reqs),
+                plen=plen, label=label,
             )
-        if pstate.selector is not None and pstate.selector.observe(dt):
-            self._on_tuned(pstate)
-        toks = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
-        # a resumed (preempted) request forces its first delivered token:
-        # greedy decode reproduces it anyway, forcing guarantees bit-match
-        first_toks: Dict[int, int] = {}
-        for i, w in enumerate(group):
-            r = w.req
-            tok0 = int(w.resume[0]) if w.resume else int(toks[i])
-            first_toks[r.rid] = tok0
-            if r.rid not in self._delivered:
-                self._delivered.add(r.rid)
-                self.stats.ttft_s[r.rid] = now - r.arrival_s
-                self.stats.tokens_out += 1
-            if r.max_new_tokens <= 1:
-                # done at first token: never allocates a block
-                self._retire(r.rid, "ok", [tok0], now, out)
-        keep_idx: List[int] = []
-        activated: List[_Waiting] = []
-        for i, w in enumerate(group):
-            if w.req.max_new_tokens <= 1:
-                continue
-            try:
-                self.cache.allocate(w.req.rid)
-            except KVPoolExhausted:
-                if not self.hardened:
-                    raise
-                # pool raced away (e.g. chaos squeeze between pick and
-                # allocate): requeue at the front with recompute state
-                resume = list(w.resume) if w.resume else [first_toks[w.req.rid]]
-                waiting.insert(0, _Waiting(
-                    req=w.req, resume=resume,
-                    preemptions=w.preemptions, deadline=w.deadline,
-                ))
-                continue
-            keep_idx.append(i)
-            activated.append(w)
-        if activated:
-            if len(keep_idx) < len(group):
-                # drop the retired/deferred rows before scattering
-                cache = _take_rows(cache, np.asarray(keep_idx, np.int32))
-            self.cache.insert([w.req.rid for w in activated], cache)
-            for w in activated:
+        with self._region("engine.prefill.commit"):
+            if pstate.selector is not None and pstate.selector.observe(dt):
+                self._on_tuned(pstate)
+            toks = np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
+            # a resumed (preempted) request forces its first delivered token:
+            # greedy decode reproduces it anyway, forcing guarantees bit-match
+            first_toks: Dict[int, int] = {}
+            for i, w in enumerate(group):
                 r = w.req
-                tok0 = first_toks[r.rid]
-                active[r.rid] = _Active(
-                    req=r, block=self.cache.block_of(r.rid),
-                    gen=[tok0], last_tok=tok0, ctx=plen,
-                    replay=list(w.resume[1:]),
-                    preemptions=w.preemptions, deadline=w.deadline,
-                )
+                tok0 = int(w.resume[0]) if w.resume else int(toks[i])
+                first_toks[r.rid] = tok0
+                if r.rid not in self._delivered:
+                    self._delivered.add(r.rid)
+                    self.stats.ttft_s[r.rid] = now - r.arrival_s
+                    self.stats.tokens_out += 1
+                if r.max_new_tokens <= 1:
+                    # done at first token: never allocates a block
+                    self._retire(r.rid, "ok", [tok0], now, out)
+            keep_idx: List[int] = []
+            activated: List[_Waiting] = []
+            for i, w in enumerate(group):
+                if w.req.max_new_tokens <= 1:
+                    continue
+                try:
+                    self.cache.allocate(w.req.rid)
+                except KVPoolExhausted:
+                    if not self.hardened:
+                        raise
+                    # pool raced away (e.g. chaos squeeze between pick and
+                    # allocate): requeue at the front with recompute state
+                    resume = list(w.resume) if w.resume else [first_toks[w.req.rid]]
+                    waiting.insert(0, _Waiting(
+                        req=w.req, resume=resume,
+                        preemptions=w.preemptions, deadline=w.deadline,
+                    ))
+                    continue
+                keep_idx.append(i)
+                activated.append(w)
+            if activated:
+                if len(keep_idx) < len(group):
+                    # drop the retired/deferred rows before scattering
+                    cache = _take_rows(cache, np.asarray(keep_idx, np.int32))
+                self.cache.insert([w.req.rid for w in activated], cache)
+                for w in activated:
+                    r = w.req
+                    tok0 = first_toks[r.rid]
+                    active[r.rid] = _Active(
+                        req=r, block=self.cache.block_of(r.rid),
+                        gen=[tok0], last_tok=tok0, ctx=plen,
+                        replay=list(w.resume[1:]),
+                        preemptions=w.preemptions, deadline=w.deadline,
+                    )
         return now
 
     # -- decode --------------------------------------------------------------
@@ -1319,58 +1428,63 @@ class StreamingEngine:
         if A == 0:
             return now
         bucket = bucket_pow2(A)
-        # pad to the pow2 bucket by replicating row 0: replicas compute the
-        # identical update, so duplicate scatter indices write equal values
-        # (well-defined) and the compile cache stays per-bucket, not per-A
-        idx = [a.block for a in act] + [act[0].block] * (bucket - A)
-        toks = [a.last_tok for a in act] + [act[0].last_tok] * (bucket - A)
-        idx_arr = jnp.asarray(idx, jnp.int32)
-        tok_arr = jnp.asarray(toks, jnp.int32)
-        len_hint = max(a.ctx for a in act)
-        dstate = self._resolve(
-            self.decode_op, self.params, self.cache.pool, idx_arr, tok_arr,
-            len_hint,
-        )
-        label = dstate.traffic.label if dstate.traffic else "decode"
-        if self.chaos is not None:
-            self.chaos.before_step("decode", rids)
-        t0 = self._timer()
-        with self.degree.region(label):
-            new_tok, pool = dstate.region(
-                self.params, self.cache.pool, idx_arr, tok_arr, len_hint
+        with self._region("engine.decode.prepare"):
+            # pad to the pow2 bucket by replicating row 0: replicas compute
+            # the identical update, so duplicate scatter indices write equal
+            # values (well-defined) and the compile cache stays per-bucket
+            idx = [a.block for a in act] + [act[0].block] * (bucket - A)
+            toks = [a.last_tok for a in act] + [act[0].last_tok] * (bucket - A)
+            idx_arr = jnp.asarray(idx, jnp.int32)
+            tok_arr = jnp.asarray(toks, jnp.int32)
+            len_hint = max(a.ctx for a in act)
+            dstate = self._resolve(
+                self.decode_op, self.params, self.cache.pool, idx_arr, tok_arr,
+                len_hint,
             )
-            new_tok.block_until_ready()
-        dt = self._timer() - t0
+            label = dstate.traffic.label if dstate.traffic else "decode"
+            if self.chaos is not None:
+                self.chaos.before_step("decode", rids)
+        with self._region("engine.decode.device", batch=A,
+                          bucket=bucket) as dev:
+            with self.degree.region(label):
+                new_tok, pool = dstate.region(
+                    self.params, self.cache.pool, idx_arr, tok_arr, len_hint
+                )
+                self._emit_regions()  # while the device runs the step
+                new_tok.block_until_ready()
+        dt = dev.t1 - dev.t0
         self.cache.pool = pool
         self.stats.decode_s += dt
         self.stats.decode_steps += 1
-        t_v0 = now
         now += dt
         if self.chaos is not None:
             now += self.chaos.step_delay()
         tr = self._tr()
         if tr is not None:
+            # emitted as the step's tokens exist, before any commit work
             tr.complete(
-                "engine.decode", t_v0, now, cat="engine", track="engine",
+                "engine.decode", dev.t0, dev.t1, cat="engine", track="engine",
                 rids=rids, batch=A, bucket=bucket, label=label,
             )
-        if dstate.selector is not None and dstate.selector.observe(dt):
-            self._on_tuned(dstate)
-        new_np = np.asarray(new_tok)[:A]
-        for a, t in zip(act, new_np):
-            if a.replay:
-                # recompute of an already-delivered token (post-preemption):
-                # force the original trajectory, don't re-count delivery
-                tok = int(a.replay.pop(0))
-            else:
-                tok = int(t)
-                self.stats.tokens_out += 1
-            a.gen.append(tok)
-            a.last_tok = tok
-            a.ctx += 1
-            if len(a.gen) >= a.req.max_new_tokens:
-                self._retire(a.req.rid, "ok", a.gen, now, out)
-                del active[a.req.rid]
+        with self._region("engine.decode.commit"):
+            if dstate.selector is not None and dstate.selector.observe(dt):
+                self._on_tuned(dstate)
+            new_np = np.asarray(new_tok)[:A]
+            for a, t in zip(act, new_np):
+                if a.replay:
+                    # recompute of an already-delivered token (post-
+                    # preemption): force the original trajectory, don't
+                    # re-count delivery
+                    tok = int(a.replay.pop(0))
+                else:
+                    tok = int(t)
+                    self.stats.tokens_out += 1
+                a.gen.append(tok)
+                a.last_tok = tok
+                a.ctx += 1
+                if len(a.gen) >= a.req.max_new_tokens:
+                    self._retire(a.req.rid, "ok", a.gen, now, out)
+                    del active[a.req.rid]
         return now
 
     # -- scheduler-knob cost: measured shadow replay -------------------------
@@ -1505,7 +1619,7 @@ def _make_decode_rows(cfg: ModelConfig):
     server's batched decode.
     """
 
-    def decode_rows(params, pool, idx, toks):
+    def engine_decode(params, pool, idx, toks):
         rows = {k: v[idx] for k, v in pool.items()}
 
         def body(tok, row):
@@ -1521,7 +1635,7 @@ def _make_decode_rows(cfg: ModelConfig):
         new_pool = {k: pool[k].at[idx].set(new_rows[k]) for k in pool}
         return new_tok, new_pool
 
-    return decode_rows
+    return engine_decode
 
 
 def _take_rows(cache: Dict[str, Any], keep: np.ndarray) -> Dict[str, Any]:

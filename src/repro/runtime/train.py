@@ -84,9 +84,10 @@ class TrainLoopConfig:
 def make_train_step(
     cfg: ModelConfig, opt_cfg: AdamWConfig, n_microbatches: int
 ) -> Callable:
-    """Build the pure train step for one microbatch degree."""
+    """Build the pure train step for one microbatch degree (named so the
+    profiler names its program ``jit_train_step``)."""
 
-    def step_fn(params, opt_state, batch):
+    def train_step(params, opt_state, batch):
         if n_microbatches == 1:
             loss, grads = jax.value_and_grad(lambda p: train_loss(p, batch, cfg))(
                 params
@@ -132,7 +133,7 @@ def make_train_step(
         metrics["loss"] = loss
         return params, opt_state, metrics
 
-    return step_fn
+    return train_step
 
 
 class Trainer:

@@ -377,9 +377,10 @@ def test_engine_timeline_retire_uniqueness(smoke_params):
     _retire_check(eng, tracer, reqs)
 
 
-def test_engine_events_carry_virtual_clock_timestamps(smoke_params):
+def test_engine_events_carry_timer_timestamps(smoke_params):
     """prefill/decode complete-events sit inside the serve span and never
-    run backwards — the timeline is on the virtual clock, not wall time."""
+    run backwards, and each is stamped with its device region's two timer
+    reads: the engine's events are on its measurement timer."""
     _, tracer, _ = _traced_run(smoke_params)
     evs = tracer.events()
     serve = [e for e in evs if e["name"] == "engine.serve"]
@@ -387,9 +388,186 @@ def test_engine_events_carry_virtual_clock_timestamps(smoke_params):
     lo, hi = serve[0]["ts"], serve[0]["ts"] + serve[0]["dur"]
     steps = [e for e in evs if e["name"] in ("engine.prefill", "engine.decode")]
     assert steps
+    device = {(e["ts"], e["dur"]) for e in evs if e["name"].endswith(".device")}
     for e in steps:
         assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi
         assert e["dur"] >= 0
+        assert (e["ts"], e["dur"]) in device
+    stamped = [e for e in evs if e["name"] in
+               ("engine.admit", "engine.retire", "engine.preempt")]
+    assert stamped and all(lo <= e["ts"] <= hi for e in stamped)
+    assert all("queue_wait_s" not in e["args"] for e in evs
+               if e["name"] == "engine.admit")
+
+
+def _by_iter(evs):
+    iters = {e["args"]["iter"]: e for e in evs if e["name"] == "engine.iter"}
+    children = {}
+    for e in evs:
+        if e["name"].startswith("engine.") and e["name"] not in (
+                "engine.iter", "engine.queue", "engine.serve") and \
+                e["ph"] == "X" and "iter" in e["args"]:
+            children.setdefault(e["args"]["iter"], []).append(e)
+    return iters, children
+
+
+def test_engine_iter_regions_nest_lifo(smoke_params):
+    """On the TickTimer clock every ``engine.iter`` holds its
+    ``engine.schedule`` and, per step, ``prepare`` -> ``device`` ->
+    ``commit``; the children of a pass follow one another inside it (LIFO
+    on one thread: none overlaps another)."""
+    eng, tracer, _ = _traced_run(smoke_params)
+    iters, children = _by_iter(tracer.events())
+    assert sorted(iters) == list(range(eng.stats.iterations))
+    assert set(children) <= set(iters)
+    kinds = set()
+    for n, it in iters.items():
+        kids = sorted(children.get(n, []), key=lambda e: e["ts"])
+        assert kids and kids[0]["name"] == "engine.schedule"
+        end = it["ts"]
+        for prev, e in zip([None] + kids, kids):
+            assert e["ts"] >= end, (prev, e)
+            end = e["ts"] + e["dur"]
+            assert end <= it["ts"] + it["dur"]
+            if e["name"].endswith(".device"):
+                step = e["name"].rsplit(".", 1)[0]
+                assert prev["name"] == step + ".prepare"
+            if e["name"].endswith(".commit"):
+                assert prev["name"] == e["name"][:-len("commit")] + "device"
+            kinds.add(e["name"])
+    assert {"engine.prefill.prepare", "engine.prefill.device",
+            "engine.prefill.commit", "engine.decode.prepare",
+            "engine.decode.device", "engine.decode.commit"} <= kinds
+
+
+def test_engine_queue_span_per_prefilled_request(smoke_params):
+    """One ``engine.queue`` per request that reached a prefill, ending as
+    the first prefill that serves it starts."""
+    eng, tracer, _ = _traced_run(smoke_params)
+    evs = tracer.events()
+    first = {}
+    for e in sorted(evs, key=lambda e: e["ts"]):
+        if e["name"] == "engine.prefill":
+            for rid in e["args"]["rids"]:
+                first.setdefault(rid, e["ts"])
+    queues = [e for e in evs if e["name"] == "engine.queue"]
+    assert first and sorted(e["args"]["rid"] for e in queues) == sorted(first)
+    for e in queues:
+        assert e["dur"] >= 0
+        assert e["ts"] + e["dur"] <= first[e["args"]["rid"]]
+    assert eng.stats.queue_waits == len(queues)
+
+
+def test_engine_counters_equal_span_sums(smoke_params):
+    """``StreamStats``' loop counters are the sums over the engine's spans
+    (TickTimer: every stamp is a whole millisecond, exact in µs)."""
+    eng, tracer, _ = _traced_run(smoke_params)
+    evs = tracer.events()
+
+    def total(pred):
+        return sum(e["dur"] for e in evs if e["ph"] == "X" and pred(e["name"])) / 1e6
+
+    s = eng.stats
+    device = total(lambda n: n.endswith(".device"))
+    assert s.host_s == pytest.approx(total(lambda n: n == "engine.iter") - device)
+    assert s.schedule_s == pytest.approx(total(lambda n: n == "engine.schedule"))
+    assert s.prepare_s == pytest.approx(total(lambda n: n.endswith(".prepare")))
+    assert s.commit_s == pytest.approx(total(lambda n: n.endswith(".commit")))
+    assert s.queue_wait_s == pytest.approx(total(lambda n: n == "engine.queue"))
+    assert s.prefill_s + s.decode_s == pytest.approx(device)
+    decode = [e["args"] for e in evs if e["name"] == "engine.decode.device"]
+    assert s.decode_rows_live == sum(a["batch"] for a in decode) > 0
+    assert s.decode_rows_run == sum(a["bucket"] for a in decode)
+    assert s.host_s > 0 and s.iterations > 0
+    metrics = s.as_metrics()
+    assert not {"tok_per_s", "ttft_p50_s", "ttft_p99_s", "makespan_s",
+                "idle_s"} & set(metrics)
+    for key in ("iterations", "host_s", "schedule_s", "prepare_s",
+                "commit_s", "queue_wait_s", "queue_waits",
+                "decode_rows_live", "decode_rows_run"):
+        assert metrics[key] == getattr(s, key)
+
+
+def test_region_writes_both_traces(tmp_path):
+    """A Region emits one complete event to its tracer (or the installed
+    one) with the attrs given at open and at close, and lands in a
+    ``jax.profiler`` capture as a host annotation with those attrs."""
+    from jax.profiler import ProfileData
+
+    from repro.obs import Region
+
+    tracer = Tracer(clock=TickTimer(1.0))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with Region("t.outer", tracer, TickTimer(1.0), a=1) as outer:
+            pass
+        with use_tracer(tracer):
+            Region("t.open", clock=TickTimer(1.0), b=2).close(c=3)
+        Region("t.untraced").close()
+    finally:
+        jax.profiler.stop_trace()
+    evs = {e["name"]: e for e in tracer.events()}
+    assert set(evs) == {"t.outer", "t.open"}
+    assert (outer.t0, outer.t1) == (1.0, 2.0)
+    assert evs["t.outer"]["ts"] == 1_000_000 and evs["t.outer"]["dur"] == 1_000_000
+    assert evs["t.outer"]["args"] == {"a": 1}
+    assert evs["t.open"]["args"] == {"b": 2, "c": 3}
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    host = {e.name: dict(e.stats) for p in pd.planes for ln in p.lines
+            for e in ln.events if e.name.startswith("t.")}
+    assert host == {"t.outer": {"a": 1}, "t.open": {"b": 2, "c": 3},
+                    "t.untraced": {}}
+
+
+def test_deferred_region_emits_when_asked():
+    """A DeferredRegion ends with its block (its clock read, its attrs) but
+    writes its tracer event only at ``emit``; a Region's ``close`` is
+    ``end`` then ``emit``."""
+    from repro.obs import DeferredRegion, Region
+
+    tracer = Tracer(clock=TickTimer(1.0))
+    with DeferredRegion("t.later", tracer, TickTimer(1.0), a=1) as later:
+        pass
+    assert (later.t0, later.t1) == (1.0, 2.0)
+    assert tracer.events() == []
+    now = Region("t.now", tracer, TickTimer(1.0))
+    assert now.end(b=2) == 1.0 and tracer.events() == []
+    now.emit()
+    later.emit()
+    evs = tracer.events()
+    assert [e["name"] for e in evs] == ["t.now", "t.later"]
+    assert evs[0]["args"] == {"b": 2} and evs[1]["args"] == {"a": 1}
+    assert all(e["ts"] == 1_000_000 and e["dur"] == 1_000_000 for e in evs)
+
+
+def test_engine_regions_in_profiler_trace(smoke_params, tmp_path):
+    """A ``jax.profiler`` capture of a tiny engine run: its host plane
+    carries every engine region, with the decode step's rows as metadata,
+    and names the programs after the engine's jitted functions."""
+    from jax.profiler import ProfileData
+
+    reqs = synthetic_requests(SMOKE, 3, prompt_len=3, max_new_tokens=3, seed=2)
+    eng = StreamingEngine(SMOKE, smoke_params, n_blocks=2, max_len=MAX_LEN)
+    eng.serve(reqs)  # compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.serve(synthetic_requests(SMOKE, 3, prompt_len=3, max_new_tokens=3,
+                                     seed=3))
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    host = [(e.name, dict(e.stats)) for p in pd.planes for ln in p.lines
+            for e in ln.events]
+    names = {n for n, _ in host}
+    assert {"engine.serve", "engine.iter", "engine.schedule", "engine.queue",
+            "engine.prefill.prepare", "engine.prefill.device",
+            "engine.prefill.commit", "engine.decode.prepare",
+            "engine.decode.device", "engine.decode.commit"} <= names
+    decode = [m for n, m in host if n == "engine.decode.device"]
+    assert decode and all(1 <= m["batch"] <= m["bucket"] <= 2 for m in decode)
+    assert len([m for n, m in host if n == "engine.queue" and "iter" in m]) == 3
+    programs = " ".join(names)
+    assert "engine_prefill" in programs and "engine_decode" in programs
 
 
 if given is not None:
