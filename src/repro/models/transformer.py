@@ -585,34 +585,14 @@ def decode_step(
     positions: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     """Returns (logits (B, V) fp32, updated cache)."""
-    B = tokens.shape[0]
     pos_now = cache["len"]  # scalar int32 — position of the incoming token
-    if positions is None:
-        pos = jnp.broadcast_to(pos_now, (B, 1)).astype(jnp.int32)
-        positions = jnp.broadcast_to(pos, (3, B, 1)) if cfg.mrope else pos
-    x = embed(tokens, params["embed"])
+    x, positions = decode_inputs(params, tokens, pos_now, cfg, positions)
 
     if cfg.family in ("dense", "vlm", "moe"):
-        cap = cache["k"].shape[2]
-
         def body(h, inputs):
             lp, ck, cv = inputs
-            hh = rmsnorm(h, lp["ln1"], cfg.norm_eps)
-            q, k, v = project_qkv(hh, lp["attn"], cfg, positions)
-            ck = lax.dynamic_update_slice_in_dim(
-                ck, k.astype(ck.dtype), pos_now, axis=1
-            )
-            cv = lax.dynamic_update_slice_in_dim(
-                cv, v.astype(cv.dtype), pos_now, axis=1
-            )
-            o = decode_attention(q, ck, cv, pos_now + 1)
-            h = h + output_proj(o, lp["attn"])
-            hh = rmsnorm(h, lp["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                delta, _ = moe_block(hh, lp["moe"], cfg)
-            else:
-                delta = swiglu(hh, lp["mlp"])
-            return h + delta, (ck, cv)
+            h, kv, _ = decode_attn_layer(h, lp, ck, cv, cfg, positions, pos_now)
+            return h, kv
 
         x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
         new_cache = {"k": ks, "v": vs, "len": pos_now + 1}
@@ -631,9 +611,53 @@ def decode_step(
     else:
         raise ValueError(cfg.family)
 
+    return decode_logits(params, x, cfg), new_cache
+
+
+def decode_inputs(
+    params: Dict[str, Any],
+    tokens: jnp.ndarray,  # (B, 1)
+    pos_now: jnp.ndarray,
+    cfg: ModelConfig,
+    positions: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The embedded token and its positions (every row at ``pos_now``
+    unless ``positions`` is given) for a one-token decode."""
+    B = tokens.shape[0]
+    if positions is None:
+        pos = jnp.broadcast_to(pos_now, (B, 1)).astype(jnp.int32)
+        positions = jnp.broadcast_to(pos, (3, B, 1)) if cfg.mrope else pos
+    return embed(tokens, params["embed"]), positions
+
+
+def decode_attn_layer(h, lp, ck, cv, cfg: ModelConfig, positions, pos_now):
+    """One dense / vlm / moe layer of a one-token decode.
+
+    The token's K and V go into slot ``pos_now`` of the layer's cache rows
+    ``ck``/``cv`` (B, cap, kv, hd), the query attends over their first
+    ``pos_now + 1`` slots, then the MLP or the MoE block runs.  Returns
+    ``(h, (ck, cv), (k, v))``: the updated rows, and the new slot's K and V
+    as stored (B, 1, kv, hd) for a caller that keeps the rows elsewhere.
+    """
+    hh = rmsnorm(h, lp["ln1"], cfg.norm_eps)
+    q, k, v = project_qkv(hh, lp["attn"], cfg, positions)
+    k, v = k.astype(ck.dtype), v.astype(cv.dtype)
+    ck = lax.dynamic_update_slice_in_dim(ck, k, pos_now, axis=1)
+    cv = lax.dynamic_update_slice_in_dim(cv, v, pos_now, axis=1)
+    o = decode_attention(q, ck, cv, pos_now + 1)
+    h = h + output_proj(o, lp["attn"])
+    hh = rmsnorm(h, lp["ln2"], cfg.norm_eps)
+    if cfg.family == "moe":
+        delta, _ = moe_block(hh, lp["moe"], cfg)
+    else:
+        delta = swiglu(hh, lp["mlp"])
+    return h + delta, (ck, cv), (k, v)
+
+
+def decode_logits(params: Dict[str, Any], x: jnp.ndarray, cfg: ModelConfig):
+    """Final norm and unembedding of a one-token decode: (B, V) fp32."""
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = _logits(params, x, cfg)[:, 0]
-    return logits, new_cache
+    return _logits(params, x, cfg)[:, 0]
 
 
 def _hybrid_decode(params, x, cache, cfg, positions, pos_now):

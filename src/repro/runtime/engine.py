@@ -16,8 +16,9 @@ that with the engine shape every production LLM server converged on
   :class:`BlockAllocator` and a ``block_table`` (rid → block).  Blocks here
   are sequence-granular — one block holds one request's whole KV row at
   fixed capacity, the honest granularity for a cache dict whose layout the
-  model owns — so decode batches compose by *index gather/scatter* into the
-  pool instead of the ``_cache_chunk``/``_cache_concat`` copy round-trips.
+  model owns — so decode batches compose by *index* into the pool, which
+  every step updates in place, instead of the
+  ``_cache_chunk``/``_cache_concat`` copy round-trips.
 
 The paper's posture carries over intact.  Prefill groups and decode gathers
 dispatch through registry ops (``engine_prefill`` / ``engine_decode``) whose
@@ -34,8 +35,10 @@ shadow replay as the cost.  The DegreeController is thereby demoted from
 "the serving policy" to one policy among the scheduler's knobs.
 
 Decode composes heterogeneous positions by ``jax.vmap`` of the batch-1
-decode step over gathered pool rows: ``cache["len"]`` is scalar per row, so
-every request advances at its own position, and
+layer math over the pool's rows (:func:`_make_decode_rows`: attention KV
+reads each layer's rows inside the layer scan and writes one slot per row;
+recurrent state gathers and scatters whole rows): ``cache["len"]`` is
+scalar per row, so every request advances at its own position, and
 :func:`~repro.models.attention.decode_attention` masks unwritten slots with
 ``-inf`` — extra pool capacity is numerically inert, which is what makes the
 engine bit-match the one-request-at-a-time reference (the conformance test).
@@ -64,10 +67,12 @@ crashes or wedges the engine; every request retires exactly once with a
   joins the tuned scheduler knobs;
 * **fault isolation** — a prefill/decode step that raises is retried one
   request at a time; a request that still raises retires ``error`` (block
-  released) and the engine continues.  A watchdog counts scheduler
-  iterations with no retire/admit/decode progress and raises
-  :class:`EngineStalled` with a state dump after ``watchdog_limit`` of them
-  — loud failure instead of a silent spin;
+  released) and the engine continues.  The pool is donated to every step,
+  so a fault after a call consumed it retires the step's rows instead
+  (:meth:`StreamingEngine._decode_step` states the contract).  A watchdog
+  counts scheduler iterations with no retire/admit/decode progress and
+  raises :class:`EngineStalled` with a state dump after ``watchdog_limit``
+  of them — loud failure instead of a silent spin;
 * **chaos** — :class:`~repro.runtime.chaos.ChaosInjector` hooks (step
   faults, pool pressure, virtual delays) make every path above a
   deterministic CI test.
@@ -79,6 +84,7 @@ the hardened engine survives.
 """
 from __future__ import annotations
 
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
@@ -86,6 +92,7 @@ from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core import (
     ATRegion,
@@ -105,6 +112,11 @@ from repro.data.pipeline import ServingRequest
 from repro.obs.trace import DeferredRegion, Region, current_tracer
 from repro.distributed.sharding import mesh_bp_entries
 from repro.models import cache_batch_axis, decode_fn, init_cache, prefill_fn
+from repro.models.transformer import (
+    decode_attn_layer,
+    decode_inputs,
+    decode_logits,
+)
 from repro.models.config import ModelConfig
 from repro.runtime.background_tuner import BackgroundTuner
 from repro.runtime.serve import (
@@ -190,10 +202,16 @@ class PagedKVCache:
 
     Every leaf of the model's cache dict for batch 1 at fixed ``capacity``
     is stacked under a leading ``(n_blocks,)`` axis; the scalar ``len`` leaf
-    becomes ``(n_blocks,)`` so each block carries its own position.  Insert
-    scatters prefilled rows into allocated blocks; decode gathers rows by
-    block index, steps them, and scatters the updated rows back — all under
-    one jit, with no split/concat copies of the full cache.
+    becomes ``(n_blocks,)`` so each block carries its own position.  The
+    pool is updated in place: the row insert and the engine's decode step
+    both donate it and hand back the pool that replaces it.  Insert
+    scatters prefilled rows into allocated blocks.  Decode, for attention
+    KV, reads each layer's rows inside its layer scan and writes one slot
+    per row and layer; for recurrent state it gathers the rows, steps them
+    and scatters them back (:func:`_make_decode_rows`).  A donated pool is
+    deleted, so whoever calls a donating program rebinds :attr:`pool` to its
+    output at once; :meth:`lost` tells a fault handler that the pool was
+    consumed with nothing to replace it, and :meth:`reset` empties it.
     """
 
     def __init__(self, cfg: ModelConfig, n_blocks: int, capacity: int) -> None:
@@ -201,12 +219,15 @@ class PagedKVCache:
         self.capacity = int(capacity)
         self.allocator = BlockAllocator(n_blocks)
         self.block_table: Dict[int, int] = {}
-        row = jax.eval_shape(lambda: init_cache(cfg, 1, capacity))
-        self.pool: Dict[str, jnp.ndarray] = {
-            k: jnp.zeros((n_blocks,) + tuple(v.shape), v.dtype)
+        self.pool: Dict[str, jnp.ndarray] = self.empty_pool()
+
+    def empty_pool(self) -> Dict[str, jnp.ndarray]:
+        """A zeroed pool of this cache's shape."""
+        row = jax.eval_shape(lambda: init_cache(self.cfg, 1, self.capacity))
+        return {
+            k: jnp.zeros((self.n_blocks,) + tuple(v.shape), v.dtype)
             for k, v in row.items()
         }
-        self._insert_jit = jax.jit(_insert_rows)
 
     @property
     def n_blocks(self) -> int:
@@ -242,7 +263,16 @@ class PagedKVCache:
         row ``i`` lands in ``rids[i]``'s allocated block.
         """
         slots = jnp.asarray([self.block_table[r] for r in rids], jnp.int32)
-        self.pool = self._insert_jit(self.pool, cache, slots)
+        self.pool = _INSERT_ROWS(self.pool, cache, slots)
+
+    def lost(self) -> bool:
+        """Whether a donating call consumed :attr:`pool` without returning
+        the pool that replaces it (its buffers are deleted)."""
+        return any(v.is_deleted() for v in self.pool.values())
+
+    def reset(self) -> None:
+        """Replace the pool with an empty one; every row's KV is gone."""
+        self.pool = self.empty_pool()
 
 
 def _insert_rows(pool, cache, slots):
@@ -263,6 +293,10 @@ def _insert_rows(pool, cache, slots):
     return out
 
 
+#: the row insert, with the pool donated: it writes the rows in place
+_INSERT_ROWS = jax.jit(_insert_rows, donate_argnums=0)
+
+
 # ---------------------------------------------------------------------------
 # Engine stats
 # ---------------------------------------------------------------------------
@@ -273,8 +307,9 @@ class StreamStats:
     tokens_out: int = 0          # tokens delivered to real requests, only
     prefill_steps: int = 0       # scheduler iterations that ran a prefill
     decode_steps: int = 0        # scheduler iterations' decode micro-steps
+    decode_inplace_steps: int = 0  # decode steps that wrote one slot per row
     prefill_calls: int = 0       # underlying jitted prefill invocations
-    decode_calls: int = 0        # underlying jitted gather-step invocations
+    decode_calls: int = 0        # underlying jitted decode-step invocations
     prefill_s: float = 0.0
     decode_s: float = 0.0
     idle_s: float = 0.0          # virtual-clock time with nothing runnable
@@ -317,6 +352,7 @@ class StreamStats:
             "tokens_out": self.tokens_out,
             "prefill_steps": self.prefill_steps,
             "decode_steps": self.decode_steps,
+            "decode_inplace_steps": self.decode_inplace_steps,
             "prefill_calls": self.prefill_calls,
             "decode_calls": self.decode_calls,
             "prefill_s": self.prefill_s,
@@ -491,7 +527,17 @@ class StreamingEngine:
             return prefill_fn(p, b, cfg, capacity=cap)
 
         self._prefill_raw = jax.jit(engine_prefill)
-        self._decode_raw = jax.jit(_make_decode_rows(cfg))
+        # donates the pool: whoever calls it rebinds the pool to its output
+        self._decode_raw = decode_program(cfg)
+        self.inplace = slot_layout(self.cache.pool)  # the slot-write path
+        # tuner trials step a pool of their own (made on the first trial):
+        # the live one is donated by every step, and a background job's
+        # arguments outlive it
+        self._trial_pool: Optional[Dict[str, jnp.ndarray]] = None
+        self._trial_lock = threading.Lock()
+        # set once a decode call has returned the pool that replaces the
+        # live one (the step's fault contract, _decode_step)
+        self._pool_stepped = False
 
         def counted_prefill(p, b):
             self.stats.prefill_calls += 1
@@ -641,27 +687,21 @@ class StreamingEngine:
         )
 
     def _make_decode_op(self) -> AutotunedOp:
+        """The ``engine_decode`` op.  Its candidates are what the tuner
+        measures and warms: the same donating program at the same shapes
+        as the hot path, on :meth:`_trial_decode`'s pool, never on the live
+        pool they are handed.  The hot path runs the selected degree on the
+        live pool itself (:meth:`_decode_exec`)."""
         cfg, mesh, cap = self.cfg, self.mesh, self.max_len
-        decode = self._decode
 
         def instantiate(point):
             d = int(point.get("degree", 1))
-            if d == 1:
-                # len_hint is scheduler metadata for the traffic class only
-                return lambda params, pool, idx, toks, len_hint=0: decode(
-                    params, pool, idx, toks
-                )
 
-            def chunked(params, pool, idx, toks, len_hint=0):
-                n = idx.shape[0] // d
-                outs = []
-                for i in range(d):
-                    sl = slice(i * n, (i + 1) * n)
-                    tok_i, pool = decode(params, pool, idx[sl], toks[sl])
-                    outs.append(tok_i)
-                return jnp.concatenate(outs, axis=0), pool
+            # len_hint is scheduler metadata for the traffic class only
+            def trial(params, pool, idx, toks, len_hint=0):
+                return self._trial_decode(d, params, idx, toks)
 
-            return chunked
+            return trial
 
         def shape_class(params, pool, idx, toks, len_hint=0) -> BasicParams:
             return BasicParams.make(
@@ -779,6 +819,17 @@ class StreamingEngine:
             spec, db=self.db, tune=self.inline_tune, warm=False, monitor=False,
             device_key=self.device_key,
         )
+
+    def _trial_decode(self, d: int, params, idx, toks) -> jnp.ndarray:
+        """One tuner trial of degree ``d``: the rows ``idx`` of the trial
+        pool, donated and rebound like the live pool; returns the tokens."""
+        with self._trial_lock:
+            if self._trial_pool is None or any(
+                    v.is_deleted() for v in self._trial_pool.values()):
+                self._trial_pool = self.cache.empty_pool()
+            new_tok, self._trial_pool = _decode_degree(
+                self._decode, d, params, self._trial_pool, idx, toks)
+        return new_tok
 
     # -- tuning hand-off (same contract as Server._resolve) ------------------
 
@@ -1268,8 +1319,11 @@ class StreamingEngine:
             return self._prefill_exec(group, active, waiting, out, now)
         try:
             return self._prefill_exec(group, active, waiting, out, now)
-        except Exception:
+        except Exception as exc:
             self.stats.step_faults += 1
+            # a row insert that consumed the pool took every in-flight
+            # row's KV with it (the contract of _decode_step)
+            self._after_dispatch(active, [], out, now, exc, step="prefill")
             # undo partial state: blocks allocated to members that never
             # activated (cache.release is rid-idempotent)
             for w in group:
@@ -1390,27 +1444,71 @@ class StreamingEngine:
     def _decode_step(
         self, active: Dict[int, _Active], out: Dict[int, List[int]], now: float
     ) -> float:
+        """One decode step of every active row, hardened by this contract.
+
+        * A fault before the step's call has consumed the pool (the chaos
+          injector's ``before_step``, preparation, a call that raises before
+          running) leaves the pool as it was: the rows are stepped again one
+          at a time, and a row that raises again retires ``error``.
+        * The call donates the pool.  Once it has consumed it, a fault never
+          touches the deleted buffers: the rows of the step still in flight
+          retire ``error`` with the tokens they delivered.  If the call
+          returned, the pool is its output and the other rows keep their
+          KV; if it consumed the pool and returned nothing, the pool is made
+          anew, empty, and every in-flight request retires ``error``.
+        """
         if not self.hardened:
             return self._decode_exec(active, out, now)
         try:
             return self._decode_exec(active, out, now)
-        except Exception:
+        except Exception as exc:
             self.stats.step_faults += 1
+            if self._after_dispatch(active, list(active), out, now, exc):
+                return now
             # isolate: step each row on its own; a row that raises again is
-            # the implicated request (its KV pool state is untouched — the
-            # jitted step is functional, the pool only swaps on success)
+            # the implicated request
             for rid in list(active.keys()):
                 if rid not in active:
                     continue
                 try:
                     now = self._decode_exec(active, out, now, only=[rid])
                 except Exception as exc:
+                    if self._after_dispatch(active, [rid], out, now, exc):
+                        continue
                     a = active.pop(rid)
                     self._retire(
                         rid, "error", a.gen, now, out,
                         detail=f"decode fault: {type(exc).__name__}: {exc}",
                     )
             return now
+
+    def _after_dispatch(
+        self,
+        active: Dict[int, _Active],
+        rids: Sequence[int],
+        out: Dict[int, List[int]],
+        now: float,
+        exc: Exception,
+        step: str = "decode",
+    ) -> bool:
+        """The fault contract once a step's call has consumed the pool
+        (:meth:`_decode_step`); False, doing nothing, if it has not."""
+        lost = self.cache.lost()
+        if not (lost or (step == "decode" and self._pool_stepped)):
+            return False
+        if lost:
+            self.cache.reset()
+            rids = list(active)
+        for rid in rids:
+            a = active.pop(rid, None)
+            if a is not None:
+                self._retire(
+                    rid, "error", a.gen, now, out,
+                    detail=(f"{step} fault after dispatch"
+                            f"{' (pool lost)' if lost else ''}: "
+                            f"{type(exc).__name__}: {exc}"),
+                )
+        return True
 
     def _decode_exec(
         self,
@@ -1419,6 +1517,7 @@ class StreamingEngine:
         now: float,
         only: Optional[Sequence[int]] = None,
     ) -> float:
+        self._pool_stepped = False
         rids = [
             r for r in (list(active.keys()) if only is None else only)
             if r in active
@@ -1444,18 +1543,21 @@ class StreamingEngine:
             label = dstate.traffic.label if dstate.traffic else "decode"
             if self.chaos is not None:
                 self.chaos.before_step("decode", rids)
-        with self._region("engine.decode.device", batch=A,
-                          bucket=bucket) as dev:
+        degree = int(dstate.region.selected.get("degree", 1))
+        with self._region("engine.decode.device", batch=A, bucket=bucket,
+                          path="slot" if self.inplace else "rows") as dev:
             with self.degree.region(label):
-                new_tok, pool = dstate.region(
-                    self.params, self.cache.pool, idx_arr, tok_arr, len_hint
+                new_tok, self.cache.pool = _decode_degree(
+                    self._decode, degree, self.params, self.cache.pool,
+                    idx_arr, tok_arr,
                 )
+                self._pool_stepped = True
                 self._emit_regions()  # while the device runs the step
                 new_tok.block_until_ready()
         dt = dev.t1 - dev.t0
-        self.cache.pool = pool
         self.stats.decode_s += dt
         self.stats.decode_steps += 1
+        self.stats.decode_inplace_steps += int(self.inplace)
         now += dt
         if self.chaos is not None:
             now += self.chaos.step_delay()
@@ -1606,36 +1708,118 @@ class StreamingEngine:
 
 
 # ---------------------------------------------------------------------------
-# vmapped batch-1 decode over gathered pool rows
+# The decode program: vmapped batch-1 rows over the donated pool
 # ---------------------------------------------------------------------------
 
 
-def _make_decode_rows(cfg: ModelConfig):
-    """The engine's decode kernel: gather rows → vmap(decode_fn) → scatter.
+def slot_layout(pool: Dict[str, Any]) -> bool:
+    """Whether a pool holds attention KV alone: leaves ``k``, ``v`` laid out
+    ``(n_blocks, L, 1, cap, kv, hd)`` and ``len``, so one decode step
+    changes one slot per layer of each row (the dense, vlm and moe caches).
+    Recurrent state (``conv``/``h``, ring buffers) has no such slot."""
+    return set(pool) == {"k", "v", "len"} and pool["k"].ndim == 6
 
-    Each gathered row is exactly the model's batch-1 cache (scalar ``len``
-    per row under vmap), so heterogeneous positions advance independently —
-    the capability the shared-scalar ``cache["len"]`` denies the static
-    server's batched decode.
+
+def _make_decode_rows(cfg: ModelConfig):
+    """The engine's decode step ``(params, pool, idx, toks) -> (new_tok,
+    pool)`` over the rows ``idx`` of the pool, which the engine donates, so
+    the compiled step updates it in place.
+
+    Every row is the model's batch-1 cache with its own scalar ``len``, and
+    the per-row work is ``jax.vmap`` of the batch-1 layer math, so rows at
+    different positions advance independently (and MoE rows route alone).
+    Two paths, by the pool's layout (:func:`slot_layout`):
+
+    * **slot** (attention KV): the layer scan runs outside and the rows
+      inside it.  Layer ``i`` reads its ``(bucket, 1, cap, kv, hd)`` K/V of
+      the ``idx`` rows from the pool, puts the new K/V at slot ``len`` and
+      attends over it (:func:`~repro.models.transformer.decode_attn_layer`,
+      the body ``decode_step`` runs too); the scan emits only the new slot.
+      After the scan one scatter writes ``(bucket, L, kv, hd)`` values each
+      into K and V at ``len``, and ``len + 1``; whole rows are never written.
+    * **rows** (recurrent state): gather the rows, ``vmap(decode_fn)``,
+      scatter the whole updated rows back.
+
+    Pow2 padding repeats row 0's index; its replicas compute identical
+    values, so the duplicate scatter indices write equal values.
     """
 
     def engine_decode(params, pool, idx, toks):
-        rows = {k: v[idx] for k, v in pool.items()}
-
-        def body(tok, row):
-            b: Dict[str, Any] = {"tokens": tok[None, None]}
-            if cfg.family == "vlm":
-                pos = jnp.broadcast_to(row["len"].astype(jnp.int32), (1, 1))
-                b["positions"] = jnp.broadcast_to(pos, (3, 1, 1))
-            logits, new_row = decode_fn(params, b, row, cfg)
-            return logits[0], new_row
-
-        logits, new_rows = jax.vmap(body)(toks, rows)
-        new_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        new_pool = {k: pool[k].at[idx].set(new_rows[k]) for k in pool}
-        return new_tok, new_pool
+        if slot_layout(pool):
+            return _decode_slots(cfg, params, pool, idx, toks)
+        return _decode_rows(cfg, params, pool, idx, toks)
 
     return engine_decode
+
+
+def _decode_slots(cfg: ModelConfig, params, pool, idx, toks):
+    lens = pool["len"][idx]
+    L, cap = pool["k"].shape[1], pool["k"].shape[3]
+
+    x, positions = jax.vmap(
+        lambda tok, ln: decode_inputs(params, tok[None, None], ln, cfg)
+    )(toks, lens)
+
+    def rows_of(leaf, i):
+        # layer i of each row, a dynamic slice a row: the TPU compiler
+        # would split one gather of them into copies of the whole pool
+        return jnp.stack([leaf[idx[b], i] for b in range(idx.shape[0])])
+
+    def layer(h, inputs):
+        lp, i = inputs
+
+        def row(h1, ck, cv, pos, ln):
+            h1, _, kv = decode_attn_layer(h1, lp, ck, cv, cfg, pos, ln)
+            return h1, kv
+
+        return jax.vmap(row)(h, rows_of(pool["k"], i), rows_of(pool["v"], i),
+                             positions, lens)
+
+    x, (ks, vs) = lax.scan(layer, x, (params["layers"], jnp.arange(L)))
+    logits = jax.vmap(lambda h: decode_logits(params, h, cfg)[0])(x)
+    new_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    # slot len of layer l of row idx[b]; clamped as the in-row update is
+    at = (idx[:, None], jnp.arange(L)[None, :], 0,
+          jnp.minimum(lens, cap - 1)[:, None])
+    new_pool = {
+        "k": pool["k"].at[at].set(jnp.swapaxes(ks[:, :, 0, 0], 0, 1)),
+        "v": pool["v"].at[at].set(jnp.swapaxes(vs[:, :, 0, 0], 0, 1)),
+        "len": pool["len"].at[idx].set(lens + 1),
+    }
+    return new_tok, new_pool
+
+
+def _decode_rows(cfg: ModelConfig, params, pool, idx, toks):
+    rows = {k: v[idx] for k, v in pool.items()}
+
+    def body(tok, row):
+        logits, new_row = decode_fn(params, {"tokens": tok[None, None]}, row, cfg)
+        return logits[0], new_row
+
+    logits, new_rows = jax.vmap(body)(toks, rows)
+    new_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    new_pool = {k: pool[k].at[idx].set(new_rows[k]) for k in pool}
+    return new_tok, new_pool
+
+
+def decode_program(cfg: ModelConfig):
+    """The jitted decode step the engine runs: the pool (argument 1) is
+    donated, so the program's pool output aliases its input."""
+    return jax.jit(_make_decode_rows(cfg), donate_argnums=1)
+
+
+def _decode_degree(decode, d: int, params, pool, idx, toks):
+    """Decode the rows ``idx`` in ``d`` chunks in turn (the ``degree``
+    candidate), each chunk stepping the pool the one before returned."""
+    if d == 1:
+        return decode(params, pool, idx, toks)
+    n = idx.shape[0] // d
+    outs = []
+    for i in range(d):
+        sl = slice(i * n, (i + 1) * n)
+        tok_i, pool = decode(params, pool, idx[sl], toks[sl])
+        outs.append(tok_i)
+    return jnp.concatenate(outs, axis=0), pool
 
 
 def _take_rows(cache: Dict[str, Any], keep: np.ndarray) -> Dict[str, Any]:

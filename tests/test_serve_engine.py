@@ -7,7 +7,10 @@ tail-batch + heterogeneous ``max_new_tokens`` property, engine-vs-sequential
 conformance for a dense and a VLM config, open-loop trace determinism, and
 the headline invariant carried over from the static server: an engine with a
 BackgroundTuner performs **zero** tuning cost evaluations on the hot path,
-cold and after drain — with the scheduler-knob classes tuned off it.
+cold and after drain — with the scheduler-knob classes tuned off it.  And
+the in-place decode: the slot-write program gives the tokens and the pool
+of the whole-row program it replaced, bit for bit, and recurrent state
+keeps the row path.
 """
 import jax
 import jax.numpy as jnp
@@ -17,7 +20,7 @@ import pytest
 from repro.configs import get_config
 from repro.data import bursty_open_loop_trace, synthetic_requests
 from repro.data.pipeline import ServingRequest
-from repro.models import init_params, param_specs
+from repro.models import decode_fn, init_params, param_specs, prefill_fn
 from repro.runtime import (
     BackgroundTuner,
     BlockAllocator,
@@ -25,7 +28,8 @@ from repro.runtime import (
     Server,
     StreamingEngine,
 )
-from repro.runtime.serve import _slice_axis, check_unique_rids
+from repro.runtime.engine import decode_program
+from repro.runtime.serve import _slice_axis, build_batch_inputs, check_unique_rids
 
 KEY = jax.random.PRNGKey(0)
 SMOKE = get_config("tinyllama-1.1b", smoke=True)
@@ -269,3 +273,96 @@ def test_bursty_trace_mix_matches_mixed_trace():
     for m in mixed:
         assert np.array_equal(by_rid[m.rid].prompt, m.prompt)
         assert by_rid[m.rid].max_new_tokens == m.max_new_tokens
+
+
+# ---------------------------------------------------------------------------
+# In-place decode: slot writes against the whole-row program
+# ---------------------------------------------------------------------------
+
+
+def _row_decode(cfg):
+    """The engine's decode program before the pool was updated in place:
+    gather the rows, vmap the model's batch-1 decode, scatter whole rows
+    back into an undonated pool.  The reference of the slot path."""
+
+    def engine_decode(params, pool, idx, toks):
+        rows = {k: v[idx] for k, v in pool.items()}
+
+        def body(tok, row):
+            b = {"tokens": tok[None, None]}
+            if cfg.family == "vlm":
+                pos = jnp.broadcast_to(row["len"].astype(jnp.int32), (1, 1))
+                b["positions"] = jnp.broadcast_to(pos, (3, 1, 1))
+            logits, new_row = decode_fn(params, b, row, cfg)
+            return logits[0], new_row
+
+        logits, new_rows = jax.vmap(body)(toks, rows)
+        new_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return new_tok, {k: pool[k].at[idx].set(new_rows[k]) for k in pool}
+
+    return jax.jit(engine_decode)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint16) if x.dtype.itemsize == 2 else x
+
+
+INPLACE_ARCHS = ("tinyllama-1.1b", "qwen2-vl-2b", "granite-moe-1b-a400m")
+
+
+@pytest.mark.parametrize("arch", INPLACE_ARCHS)
+def test_slot_decode_matches_row_program(arch):
+    """Rows at different lengths in scattered blocks, padded to the pow2
+    bucket by repeating row 0's index: every step gives the same tokens as
+    the whole-row program, and the same pool, bit for bit, in every live
+    block (and leaves the free blocks as they were)."""
+    cfg = get_config(arch, smoke=True)
+    params = init_params(KEY, param_specs(cfg))
+    cache = PagedKVCache(cfg, n_blocks=5, capacity=24)
+    reqs = synthetic_requests(cfg, 3, prompt_len=15, max_new_tokens=4)
+    for rid in (-1, reqs[0].rid, reqs[1].rid, -2, reqs[2].rid):
+        cache.allocate(rid)  # the rows land in blocks 1, 2 and 4
+    toks = []
+    for r, plen in zip(reqs, (9, 12, 15)):
+        r.prompt = r.prompt[:plen]
+        logits, c = prefill_fn(params, build_batch_inputs(cfg, [r], plen),
+                               cfg, capacity=24)
+        cache.insert([r.rid], c)
+        toks.append(int(jnp.argmax(logits[0])))
+    live = [cache.block_of(r.rid) for r in reqs]
+    assert len(set(live)) == 3
+    idx = jnp.asarray(live + [live[0]], jnp.int32)
+    tok = jnp.asarray(toks + [toks[0]], jnp.int32)
+    ref_pool = jax.tree.map(jnp.copy, cache.pool)
+    pool = cache.pool
+    slot, rows = decode_program(cfg), _row_decode(cfg)
+    for _ in range(4):
+        ref_tok, ref_pool = rows(params, ref_pool, idx, tok)
+        new_tok, pool = slot(params, pool, idx, tok)
+        assert np.array_equal(np.asarray(new_tok), np.asarray(ref_tok))
+        for k in pool:
+            assert np.array_equal(_bits(pool[k]), _bits(ref_pool[k])), k
+        tok = new_tok
+    assert set(pool) == {"k", "v", "len"}
+    assert np.asarray(pool["len"])[live].tolist() == [13, 16, 19]
+
+
+@pytest.mark.parametrize("arch", INPLACE_ARCHS + ("falcon-mamba-7b",))
+def test_engine_decode_path_follows_cache_layout(arch):
+    """Attention KV takes the slot path on every decode step; recurrent
+    state keeps the row path (the counter reads 0), and both serve the
+    one-request-at-a-time reference's tokens."""
+    cfg = get_config(arch, smoke=True)
+    params = init_params(KEY, param_specs(cfg))
+    trace = bursty_open_loop_trace(cfg, 3, seed=7, scale=0.25)
+    eng = StreamingEngine(cfg, params, n_blocks=2, max_len=32)
+    out = eng.serve(trace)
+    assert out == _reference(cfg, params, trace, max_len=32)
+    s = eng.stats
+    assert s.decode_steps > 0
+    inplace = cfg.family in ("dense", "vlm", "moe")
+    assert eng.inplace == inplace
+    assert s.decode_inplace_steps == (s.decode_steps if inplace else 0)
+    assert s.as_metrics()["decode_inplace_steps"] == s.decode_inplace_steps
+    assert not eng.cache.lost()

@@ -3,8 +3,9 @@
 Every candidate the emit layer produces for the v5e arch, for each of the
 five kernels at the widths of the workloads they serve, must pass the TPU
 compiler as a native Mosaic kernel (``tpu_custom_call``), and the
-qwen3-0.6b engine decode step (8 KV blocks x 2048) and train step
-(batch 1 x 2048) must each fit one chip's HBM.
+qwen3-0.6b engine decode step (8 KV blocks x 2048, 16 x 1024) and train
+step (batch 1 x 2048) must each fit one chip's HBM, updating the KV pool
+and the training state in place.
 Nothing runs, so this checks neither results nor times.
 
 The topology is described inside a fixture, never at import: only one
@@ -91,25 +92,59 @@ def test_every_emitted_candidate_compiles_natively(name, one_chip, v5e):
         assert "tpu_custom_call" in hlo, (name, point)
 
 
-def test_qwen3_engine_decode_step_fits_one_chip(one_chip):
+def _pool(blocks, capacity):
     from repro.configs import get_config
-    from repro.models import init_cache, param_specs
-    from repro.models.spec import as_shape_dtype_structs
-    from repro.runtime.engine import _make_decode_rows
+    from repro.models import init_cache
 
     cfg = get_config("qwen3-0.6b")
-    blocks, capacity = 8, 2048
     row = jax.eval_shape(lambda: init_cache(cfg, 1, capacity))
-    pool = {
+    return cfg, {
         k: jax.ShapeDtypeStruct((blocks,) + v.shape, v.dtype)
         for k, v in row.items()
     }
-    idx = jax.ShapeDtypeStruct((blocks,), jnp.int32)
+
+
+def _bytes(tree) -> int:
+    return sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("blocks,capacity,bucket",
+                         [(8, 2048, 8), (16, 1024, 16)])
+def test_qwen3_engine_decode_step_fits_one_chip(blocks, capacity, bucket,
+                                                one_chip):
+    """The engine's decode step donates the pool (its output aliases its
+    input) and writes one slot per row and layer, so it holds no copy of
+    the pool's rows: 8 x 2048, and the serving benchmark's 16 x 1024 at
+    its largest bucket."""
+    from repro.models import param_specs
+    from repro.models.spec import as_shape_dtype_structs
+    from repro.runtime.engine import decode_program
+
+    cfg, pool = _pool(blocks, capacity)
+    idx = jax.ShapeDtypeStruct((bucket,), jnp.int32)
     params = as_shape_dtype_structs(param_specs(cfg))
-    compiled = jax.jit(_make_decode_rows(cfg)).lower(
+    compiled = decode_program(cfg).lower(
         *_on(one_chip, (params, pool, idx, idx))
     ).compile()
     assert 0 < _device_bytes(compiled) <= HBM_BYTES
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 0.99 * _bytes(pool)
+    assert mem.temp_size_in_bytes <= 0.25 * _bytes(pool)
+
+
+def test_qwen3_insert_rows_donates_pool(one_chip):
+    """The row insert after a prefill writes its rows into the donated
+    pool: the program's pool output aliases its input."""
+    from repro.models import init_cache
+    from repro.runtime.engine import _INSERT_ROWS
+
+    cfg, pool = _pool(16, 1024)
+    cache = jax.eval_shape(lambda: init_cache(cfg, 2, 1024))
+    slots = jax.ShapeDtypeStruct((2,), jnp.int32)
+    compiled = _INSERT_ROWS.lower(
+        *_on(one_chip, (pool, cache, slots))
+    ).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= 0.99 * _bytes(pool)
 
 
 def test_qwen3_train_step_fits_one_chip(one_chip):
@@ -128,8 +163,7 @@ def test_qwen3_train_step_fits_one_chip(one_chip):
     step = trainer.region.candidate({"n_micro": 1})
     compiled = step.lower(*_on(one_chip, state), _on(one_chip, batch)).compile()
     assert 0 < _device_bytes(compiled) <= HBM_BYTES
-    state_bytes = sum(s.size * s.dtype.itemsize for s in jax.tree.leaves(state))
-    assert compiled.memory_analysis().alias_size_in_bytes >= 0.99 * state_bytes
+    assert compiled.memory_analysis().alias_size_in_bytes >= 0.99 * _bytes(state)
 
 
 def _device_bytes(compiled) -> int:
